@@ -15,7 +15,7 @@ from typing import Callable, Iterable
 
 import numpy as np
 
-from jax.core import Jaxpr, JaxprEqn
+from jax.extend.core import Jaxpr, JaxprEqn
 
 _TRANSFER_PRIMITIVES = {"device_put", "convert_element_type_to_host", "copy"}
 

@@ -33,6 +33,7 @@ __all__ = [
     "fake_quant_dynamic",
     "fake_quant_dynamic_token",
     "quantize_native",
+    "po2_carrier",
     "dequantize",
     "QTensor",
 ]
@@ -104,9 +105,9 @@ def fake_quant_dynamic(x: jax.Array, bits: jax.Array, signed_sym: jax.Array) -> 
     return y
 
 
-def _fqd_impl(x, bits, signed_sym, axis=None):
-    dt = x.dtype
-    xf = x.astype(jnp.float32)
+def _po2_grid(xf, bits, axis=None):
+    """Integer grid values ``q`` and power-of-two ``scale`` of the dynamic
+    signed, non-symmetric fake-quant (``q * scale`` is its output)."""
     qmin, qmax = qrange_dynamic(bits, signed=True, symmetric=False)
     if axis is None:
         amax = jnp.maximum(jnp.max(jnp.abs(xf)), 1e-9)
@@ -114,6 +115,13 @@ def _fqd_impl(x, bits, signed_sym, axis=None):
         amax = jnp.maximum(jnp.max(jnp.abs(xf), axis=axis, keepdims=True), 1e-9)
     scale = jnp.exp2(jnp.ceil(jnp.log2(amax / jnp.maximum(-qmin, qmax))))
     q = jnp.clip(jnp.sign(xf / scale) * jnp.floor(jnp.abs(xf / scale) + 0.5), qmin, qmax)
+    return q, scale, qmin, qmax
+
+
+def _fqd_impl(x, bits, signed_sym, axis=None):
+    dt = x.dtype
+    xf = x.astype(jnp.float32)
+    q, scale, qmin, qmax = _po2_grid(xf, bits, axis)
     y = q * scale
     passthrough = (bits >= 17).astype(jnp.float32)
     y = passthrough * xf + (1.0 - passthrough) * y
@@ -193,6 +201,17 @@ def quantize_native(x: jax.Array, spec: QuantSpec, scale: Optional[jax.Array] = 
     else:
         data = q.astype(carrier_dtype(spec.bits))
     return QTensor(data=data, scale=s, bits=spec.bits, orig_last=x.shape[-1])
+
+
+def po2_carrier(x: jax.Array, bits: jax.Array) -> QTensor:
+    """Int8 carrier of ``fake_quant_dynamic(x, bits)`` for ``bits <= 8``.
+
+    The per-tensor grid's scale is a power of two, so :func:`dequantize` of
+    the carrier reproduces the fake-quant values exactly, in any float dtype
+    that holds the 8-bit grid (f32, bf16). ``bits`` may be traced.
+    """
+    q, scale, _, _ = _po2_grid(x.astype(jnp.float32), bits)
+    return QTensor(q.astype(jnp.int8), scale, 8, x.shape[-1])
 
 
 def dequantize(qt: QTensor, dtype=jnp.bfloat16) -> jax.Array:

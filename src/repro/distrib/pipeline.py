@@ -25,24 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-# jax >= 0.5 moved shard_map to the top level (renaming check_rep → check_vma)
-# and added lax.pcast for the varying-manual-axes check; on 0.4.x use the
-# experimental entry point and a no-op pcast (carries need no varying mark).
-import inspect
-
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_CHECK_KW = ("check_vma" if "check_vma" in
-             inspect.signature(_shard_map).parameters else "check_rep")
-
-def _pcast(x, axes, to):
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(x, axes, to=to)
-    return x
-
 __all__ = ["pipeline_forward", "stage_split"]
 
 
@@ -99,8 +81,10 @@ def pipeline_forward(stage_fn: Callable, params_staged, x: jax.Array, *,
             return buf, outs
 
         # carries become device-varying inside the loop → mark them upfront
-        buf0 = _pcast(jnp.zeros_like(xm_all[0]), (axis_name,), to="varying")
-        outs0 = _pcast(jnp.zeros_like(xm_all), (axis_name,), to="varying")
+        buf0 = jax.lax.pcast(jnp.zeros_like(xm_all[0]), (axis_name,),
+                             to="varying")
+        outs0 = jax.lax.pcast(jnp.zeros_like(xm_all), (axis_name,),
+                              to="varying")
         _, outs = jax.lax.fori_loop(0, n_ticks, tick, (buf0, outs0))
         # only the last stage holds real outputs; broadcast via max-reduce
         outs = jax.lax.psum(
@@ -108,6 +92,6 @@ def pipeline_forward(stage_fn: Callable, params_staged, x: jax.Array, *,
             axis_name)
         return outs
 
-    y = _shard_map(body, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs, **{_CHECK_KW: False})(params_staged, xm)
+    y = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                      out_specs=out_specs, check_vma=False)(params_staged, xm)
     return y.reshape(b, *x.shape[1:])

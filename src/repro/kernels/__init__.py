@@ -7,13 +7,6 @@
 ``ref.py`` holds the pure-jnp oracles; kernels are validated in interpret
 mode on CPU (TPU v5e is the deployment target).
 """
-from jax.experimental.pallas import tpu as _pltpu
-
-# Compat alias: jax < 0.5 exposes ``TPUCompilerParams``, newer releases renamed
-# it ``CompilerParams``. Kernels import this symbol from the package so either
-# jax works. Defined before the submodule imports below (they depend on it).
-CompilerParams = getattr(_pltpu, "CompilerParams", None) or _pltpu.TPUCompilerParams
-
 from .ops import qmatmul, qmatmul_qt
 from .qmatmul import qmatmul_pallas, DEFAULT_BLOCKS
 from .qkv_attention import qkv_attention_pallas
@@ -21,5 +14,4 @@ from .paged_attention import paged_attention_pallas
 from .aquant import aquant_pallas
 
 __all__ = ["qmatmul", "qmatmul_qt", "qmatmul_pallas", "qkv_attention_pallas",
-           "paged_attention_pallas", "aquant_pallas", "DEFAULT_BLOCKS",
-           "CompilerParams"]
+           "paged_attention_pallas", "aquant_pallas", "DEFAULT_BLOCKS"]

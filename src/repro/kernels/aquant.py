@@ -25,7 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import CompilerParams
 
 __all__ = ["aquant_pallas"]
 
@@ -74,7 +73,7 @@ def aquant_pallas(x: jax.Array, *, bits: int = 8, po2: bool = True,
         out_specs=pl.BlockSpec((br, n), lambda i: (i % n_blocks, 0)),
         out_shape=jax.ShapeDtypeStruct((m + pad, n), x.dtype),
         scratch_shapes=[pltpu.SMEM((1,), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,

@@ -25,7 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import CompilerParams
 
 __all__ = ["qkv_attention_pallas"]
 
@@ -102,7 +101,7 @@ def qkv_attention_pallas(q: jax.Array, k_q: jax.Array, v_q: jax.Array,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((g, hg, d), jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

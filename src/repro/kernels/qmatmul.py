@@ -30,7 +30,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import CompilerParams
 
 __all__ = ["qmatmul_pallas", "DEFAULT_BLOCKS"]
 
@@ -128,7 +127,7 @@ def qmatmul_pallas(x: jax.Array, w_q: jax.Array, scale: jax.Array, *,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -168,9 +168,6 @@ def _lower_step(cfg, shape, mesh, *, native_bits, kv_bits, serve_layout=False):
 def _measure(compiled) -> dict:
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    # jax <= 0.4.x returns a one-element list of dicts; newer returns the dict
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
     hlo = compiled.as_text()
     coll = collective_bytes(hlo)
 
@@ -182,9 +179,8 @@ def _measure(compiled) -> dict:
         return None
 
     return dict(
-        flops=float(cost.get("flops", 0.0)) if isinstance(cost, dict) else None,
-        bytes_accessed=float(cost.get("bytes accessed", 0.0))
-        if isinstance(cost, dict) else None,
+        flops=float(cost.get("flops", 0.0)),
+        bytes_accessed=float(cost.get("bytes accessed", 0.0)),
         memory=dict(
             argument_bytes=_get(mem, "argument_size_in_bytes"),
             output_bytes=_get(mem, "output_size_in_bytes"),

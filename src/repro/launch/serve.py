@@ -15,8 +15,9 @@ from repro.configs import ARCHS, get_config, get_smoke
 from repro.core.energy import TPU_V5E, activity_factor, step_energy
 from repro.core.engine import AdaptiveEngine, QuantIndex
 from repro.core.manager import ProfileManager, ProfileStats
-from repro.core.profiles import paper_profiles
+from repro.core.profiles import PAPER_PROFILES, paper_profiles
 from repro.models import transformer as T
+from repro.runtime import compute_dtype, enable_compile_cache
 from repro.serving.engine import AdaptiveServer, Request, ServingConfig
 
 
@@ -35,7 +36,22 @@ def profile_stats(cfg, profs, n_params: int) -> list[ProfileStats]:
     return out
 
 
-def main() -> None:
+def init_masters(cfg, seed: int) -> dict:
+    """Seeded random parameters with the float matmul masters (``w``
+    leaves) held in the compute dtype: bf16 on TPU halves their resident
+    bytes; f32 on CPU leaves them as ``init_params`` makes them. Built in one
+    jit, so no float32 copy of the whole model is ever resident."""
+    cd = compute_dtype()
+
+    def init(key):
+        return jax.tree_util.tree_map_with_path(
+            lambda path, a: a.astype(cd) if path[-1].key == "w" else a,
+            T.init_params(cfg, key))
+
+    return jax.jit(init)(jax.random.PRNGKey(seed))
+
+
+def parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite-3-2b", choices=ARCHS)
     ap.add_argument("--full", action="store_true")
@@ -186,20 +202,17 @@ def main() -> None:
                          "BlockAllocator.check) after every scheduler "
                          "step. Requires --continuous")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    return ap
 
+
+def build_server(args: argparse.Namespace) -> AdaptiveServer:
+    """The server ``main`` runs, from its parsed command line: seeded
+    random weights (:func:`init_masters`), the six paper profiles behind a
+    budget :class:`ProfileManager`, and the :class:`ServingConfig` the
+    options describe."""
     cfg = get_config(args.arch) if args.full else get_smoke(args.arch)
     if not cfg.causal:
         raise SystemExit("encoder-only arch has no decode step")
-    params = T.init_params(cfg, jax.random.PRNGKey(args.seed))
-    names = T.quant_layer_names(cfg)
-    profs = paper_profiles(names, inner_layers=[])
-    engine = AdaptiveEngine(tuple(profs), QuantIndex(names),
-                            lambda p, br, b: T.train_loss(p, cfg, br, b))
-    stats = profile_stats(cfg, profs, T.param_count(params))
-    mgr = ProfileManager(stats, accuracy_target=0.985, accuracy_floor=0.95,
-                         budget_j=stats[0].energy_j * args.budget_inferences,
-                         low_energy=0.5)
     if args.preemption and not args.continuous:
         raise SystemExit("--preemption needs --continuous (the slot pool)")
     if not args.continuous and (args.deadline_ms is not None
@@ -225,30 +238,46 @@ def main() -> None:
         # profile 0 is the accuracy-critical binding: pin it to the exact
         # all-high row; the rest ride the searched frontier schedule
         policy = tuple((16,) * cfg.n_layers if i == 0 else row
-                       for i in range(len(profs)))
+                       for i in range(len(PAPER_PROFILES)))
+    params = init_masters(cfg, args.seed)
+    names = T.quant_layer_names(cfg)
+    profs = paper_profiles(names, inner_layers=[])
+    engine = AdaptiveEngine(tuple(profs), QuantIndex(names),
+                            lambda p, br, b: T.train_loss(p, cfg, br, b))
+    stats = profile_stats(cfg, profs, T.param_count(params))
+    mgr = ProfileManager(stats, accuracy_target=0.985, accuracy_floor=0.95,
+                         budget_j=stats[0].energy_j * args.budget_inferences,
+                         low_energy=0.5)
+    return AdaptiveServer(cfg, params, engine,
+                          ServingConfig(slots=256, kv_bits=args.kv_bits,
+                                        max_batch=4, paged_kv=args.paged_kv,
+                                        block_size=args.block_size,
+                                        pool_blocks=args.pool_blocks,
+                                        prefix_cache=args.prefix_cache,
+                                        paged_backend=args.paged_backend,
+                                        prefill_chunk=args.prefill_chunk,
+                                        priority_classes=args.priority_classes,
+                                        preemption=args.preemption,
+                                        aging=args.aging,
+                                        speculate=args.speculate,
+                                        draft_k=args.draft_k,
+                                        draft_model=args.draft_model,
+                                        kv16_masters=args.kv16_masters,
+                                        precision_policy=policy),
+                          manager=mgr)
+
+
+def main() -> None:
+    args = parser().parse_args()
+    enable_compile_cache()
     stop = {"drain": False}
     if args.drain_on_sigterm:
         # install before the (slow) model/executable build: a TERM during
         # warmup drains at the first step boundary instead of killing us
         import signal
         signal.signal(signal.SIGTERM, lambda *_: stop.update(drain=True))
-    srv = AdaptiveServer(cfg, params, engine,
-                         ServingConfig(slots=256, kv_bits=args.kv_bits,
-                                       max_batch=4, paged_kv=args.paged_kv,
-                                       block_size=args.block_size,
-                                       pool_blocks=args.pool_blocks,
-                                       prefix_cache=args.prefix_cache,
-                                       paged_backend=args.paged_backend,
-                                       prefill_chunk=args.prefill_chunk,
-                                       priority_classes=args.priority_classes,
-                                       preemption=args.preemption,
-                                       aging=args.aging,
-                                       speculate=args.speculate,
-                                       draft_k=args.draft_k,
-                                       draft_model=args.draft_model,
-                                       kv16_masters=args.kv16_masters,
-                                       precision_policy=policy),
-                         manager=mgr)
+    srv = build_server(args)
+    cfg, mgr = srv.cfg, srv.manager
     rng = np.random.default_rng(args.seed)
     n_cls = max(1, args.priority_classes)
     reqs = [Request(tokens=rng.integers(0, cfg.vocab, int(n)).astype(np.int32),

@@ -27,6 +27,7 @@ from repro.models import transformer as T
 from repro.optim.adam import AdamConfig
 from repro.optim.compression import (compress_tree, decompress_tree,
                                      init_error_feedback)
+from repro.runtime import enable_compile_cache
 from repro.train.loop import TrainConfig, train
 
 
@@ -46,6 +47,7 @@ def main() -> None:
                     help="int8 gradient compression with error feedback")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch) if args.full else get_smoke(args.arch)
     if cfg.frontend is not None:

@@ -684,20 +684,27 @@ def decode_attention(q: jax.Array, cache: KVCache, pos: jax.Array, *,
     b, _, h, d = q.shape
     _, slots, hkv, _ = cache.k.shape
     hg = h // hkv
-    qh = (q.astype(jnp.float32) * d ** -0.5).reshape(b, hkv, hg, d)
+    qh = (q.astype(jnp.float32) * d ** -0.5).reshape(b, 1, hkv, hg, d)
+
+    def qk(kf):
+        # the W-query contraction of decode_attention_window at W = 1: XLA
+        # sums a 4-D "bkgd" contraction in another order, and a one-token
+        # step must match a speculative verify window bit for bit
+        return jnp.einsum("bwkgd,bskd->bwkgs", qh, kf)[:, 0]
+
     if cache.bits == 8:
         # int8 fast path: contract on the int grid and fold the per-(B,Hkv)
         # dequant scale into the result — the same layout/order the Pallas
         # ``qkv_attention`` kernel uses, and no cache-sized scaled temporary
         # inside the decode scan.
-        scores = jnp.einsum("bkgd,bskd->bkgs", qh, cache.k.astype(jnp.float32))
+        scores = qk(cache.k.astype(jnp.float32))
         scores = scores * cache.k_scale[:, :, None, None]
     else:
         if cache.bits == 4:
             kf = _dequantize_kv(cache.k, cache.k_scale, cache.bits)
         else:
             kf = cache.k.astype(jnp.float32)
-        scores = jnp.einsum("bkgd,bskd->bkgs", qh, kf)     # [B,Hkv,Hg,slots]
+        scores = qk(kf)                                    # [B,Hkv,Hg,slots]
     win = jnp.asarray(slots + 1 if window is None else window, jnp.int32)
     tidx = cache.token_idx                                  # [B, slots]
     keep = (tidx >= 0) & (tidx <= pos[:, None]) & (pos[:, None] - tidx < win)

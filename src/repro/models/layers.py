@@ -65,27 +65,21 @@ def qlinear(params: dict, x: jax.Array, bits_aw: jax.Array, *,
     """
     if compute_dtype is None:
         compute_dtype = _default_compute_dtype()
+    # Activations quantize in-loop (their scale depends on runtime data);
+    # the weight side is either already on its grid or fake-quanted here.
+    xq = fake_quant_dynamic_token(x, bits_aw[0], SIGNED_SYM)
     if "wfq" in params:
-        # Decode-scan fast path: the weight image was fake-quanted *once*
-        # ahead of the loop (per profile — transformer.prequant_decode_weights)
-        # instead of every step. Activations still quantize in-loop (their
-        # scale depends on runtime data).
-        xq = fake_quant_dynamic_token(x, bits_aw[0], SIGNED_SYM)
-        y = jnp.dot(xq.astype(compute_dtype), params["wfq"].astype(compute_dtype),
-                    preferred_element_type=jnp.float32)
-    elif "w" in params:
-        a_bits, w_bits = bits_aw[0], bits_aw[1]
-        xq = fake_quant_dynamic_token(x, a_bits, SIGNED_SYM)
-        wq = fake_quant_dynamic(params["w"], w_bits, SIGNED_SYM)
-        y = jnp.dot(xq.astype(compute_dtype), wq.astype(compute_dtype),
-                    preferred_element_type=jnp.float32)
-    else:
-        # Native: activations still honor the profile's a_bits (bits-as-data);
-        # weights are already on their integer grid.
-        a_bits = bits_aw[0]
-        xq = fake_quant_dynamic_token(x, a_bits, SIGNED_SYM)
+        # Decode-scan image: the weight was fake-quanted *once* ahead of the
+        # loop (transformer.prequant_decode_weights) instead of every step.
+        w = params["wfq"].astype(compute_dtype)
+    elif "wq" in params:
+        # Integer carrier: a native deployment layout, or a decode-scan image
+        # grafted next to the float master (it wins over ``w``).
         w = dequantize(params["wq"], compute_dtype)
-        y = jnp.dot(xq.astype(compute_dtype), w, preferred_element_type=jnp.float32)
+    else:
+        w = fake_quant_dynamic(params["w"], bits_aw[1],
+                               SIGNED_SYM).astype(compute_dtype)
+    y = jnp.dot(xq.astype(compute_dtype), w, preferred_element_type=jnp.float32)
     if "b" in params:
         y = y + params["b"].astype(jnp.float32)
     return y.astype(compute_dtype)
@@ -140,7 +134,7 @@ def embed_lookup(params: dict, ids: jax.Array, bits_aw: jax.Array,
     an integer gather — the paper's data approximation acts on the table)."""
     if compute_dtype is None:
         compute_dtype = _default_compute_dtype()
-    if "wq" in params:  # native: gather int rows, dequant after (HBM win)
+    if "wq" in params:  # int carrier: gather int rows, dequant after (HBM win)
         from repro.core.qtypes import unpack_int4
         qt: QTensor = params["wq"]
         rows = jnp.take(qt.data, ids, axis=0)

@@ -51,7 +51,7 @@ __all__ = ["ModelConfig", "init_params", "quant_layer_names", "forward",
            "decode_many", "decode_segment", "prefill", "prefill_extend",
            "forward_extend", "cache_bytes", "supports_prefix_sharing",
            "paged_row_masters", "amax_for_scale",
-           "prequant_decode_weights", "overlay_params",
+           "prequant_decode_weights", "decode_image", "overlay_params",
            "param_count", "active_param_count"]
 
 
@@ -439,10 +439,10 @@ def _remat_policy(cfg: ModelConfig):
 def _lm_head_params(cfg: ModelConfig, params: dict) -> dict:
     if cfg.tie_embeddings:
         emb = params["embed"]
-        if "wq" in emb:  # native deployment: dequantize the tied table
-            from repro.core.quantizers import dequantize
-            return {"w": dequantize(emb["wq"], jnp.float32).T}
-        return {"w": emb["w"].T}
+        if "w" in emb:  # the master, also under a decode image of the table
+            return {"w": emb["w"].T}
+        from repro.core.quantizers import dequantize  # native deployment
+        return {"w": dequantize(emb["wq"], jnp.float32).T}
     return params["lm_head"]
 
 
@@ -828,66 +828,93 @@ def prequant_decode_weights(params: dict, cfg: ModelConfig,
                             table: jax.Array) -> dict:
     """Hoist weight fake-quant out of the decode loop.
 
-    The seed decode path re-fake-quanted every weight matrix (embedding table
-    and lm_head included) on *every step* — pure overhead around the
-    approximate kernels. Since weights are step-invariant, quantize them once
-    per profile up front: returns a sparse overlay pytree, parallel to
-    ``params``, whose ``wfq`` leaves carry a leading profile dim ``P`` (the
-    in-memory analogue of the MDC merge's per-profile actors). The decode scan
-    gathers slice ``pid`` per step and grafts it on with :func:`overlay_params`
-    — ``qlinear``/``embed_lookup`` prefer ``wfq`` and skip in-loop weight
-    quantization. Activation quant stays in-loop (runtime-data dependent).
+    The seed decode path re-fake-quanted every weight matrix on *every step*
+    — pure overhead around the approximate kernels. Since weights are
+    step-invariant, quantize them once up front, one image per **distinct
+    weight-width row** of the (concrete) ``[P, L, 2]`` bits table: profiles
+    that differ only in activation bits share an image (the six paper
+    profiles need two, W8 and W4). Returns ``{"image_of": int32[P],
+    "images": overlay}`` where the overlay is a sparse pytree parallel to
+    ``params`` with a leading image dim; :func:`decode_image` picks a
+    profile's image inside the traced decode step and :func:`overlay_params`
+    grafts it on.
+
+    A site that every image quantizes at ``<= 8`` bits is held as an int8
+    carrier plus its power-of-two scale (``wq`` — a :class:`QTensor`, which
+    ``qlinear``/``embed_lookup`` consume on their native branch); its
+    dequantized values equal the fake-quant exactly, so tokens do not
+    change. Any other site keeps a float fake-quant image (``wfq``). Images
+    are built one layer at a time (``lax.map``), so the float temporaries
+    stay at one layer's size.
 
     Sites not covered (MoE routed-expert stacks, tied lm_head) keep the
     in-loop path — fake-quant is idempotent on its own po2 grid, so numerics
     match either way. Native (``wq``) layouts pass through untouched.
     """
-    def one_profile(bits_row):
-        eb, hb, layer_bits = split_bits(cfg, bits_row)
-        from .layers import SIGNED_SYM
-        from repro.core.quantizers import fake_quant_dynamic
+    from .layers import SIGNED_SYM
+    from repro.core.quantizers import fake_quant_dynamic, po2_carrier
 
-        def fq(w, wb):
-            return fake_quant_dynamic(w, wb, SIGNED_SYM)
+    w_rows, image_of = np.unique(np.asarray(table)[..., 1], axis=0,
+                                 return_inverse=True)
+    ns = len(sites(cfg))
+    layer_rows = w_rows[:, 2:].reshape(len(w_rows), cfg.n_layers, ns)
 
-        def fq_stacked(w, name):          # w [L, ...] with per-layer bits
-            wb = layer_bits[:, _site_idx(cfg, name), 1]
-            return jax.vmap(fq)(w, wb)
+    def quant(w, wb, narrow):
+        if narrow:
+            return {"wq": po2_carrier(w, wb)}
+        return {"wfq": fake_quant_dynamic(w, wb, SIGNED_SYM)}
+
+    def one_image(w_row):
+        layer_bits = w_row[2:].reshape(cfg.n_layers, ns)
+
+        def glob(w, i):
+            return quant(w, w_row[i], bool((w_rows[:, i] <= 8).all()))
+
+        def stacked(w, name):             # w [L, ...] with per-layer bits
+            j = _site_idx(cfg, name)
+            narrow = bool((layer_rows[:, :, j] <= 8).all())
+            return jax.lax.map(lambda a: quant(a[0], a[1], narrow),
+                               (w, layer_bits[:, j]))
 
         ov: dict[str, Any] = {}
         if "w" in params["embed"] and cfg.frontend != "audio":
-            ov["embed"] = {"wfq": fq(params["embed"]["w"], eb[1])}
+            ov["embed"] = glob(params["embed"]["w"], 0)
         if not cfg.tie_embeddings and "w" in params.get("lm_head", {}):
-            ov["lm_head"] = {"wfq": fq(params["lm_head"]["w"], hb[1])}
+            ov["lm_head"] = glob(params["lm_head"]["w"], 1)
         lp = params["layers"]
         lov: dict[str, Any] = {}
         if cfg.has_attn and "w" in lp["qkv"]:
-            lov["qkv"] = {"wfq": fq_stacked(lp["qkv"]["w"], "qkv")}
-            lov["attn_out"] = {"wfq": fq_stacked(lp["attn_out"]["w"], "attn_out")}
+            lov["qkv"] = stacked(lp["qkv"]["w"], "qkv")
+            lov["attn_out"] = stacked(lp["attn_out"]["w"], "attn_out")
         if cfg.has_mlp and "w" in lp["mlp"]["w_in"]:
-            lov["mlp"] = {
-                "w_in": {"wfq": fq_stacked(lp["mlp"]["w_in"]["w"], "mlp_in")},
-                "w_out": {"wfq": fq_stacked(lp["mlp"]["w_out"]["w"], "mlp_out")},
-            }
+            lov["mlp"] = {"w_in": stacked(lp["mlp"]["w_in"]["w"], "mlp_in"),
+                          "w_out": stacked(lp["mlp"]["w_out"]["w"], "mlp_out")}
         if cfg.has_ssm and "w" in lp["ssm"]["in_proj"]:
             lov["ssm"] = {
-                "in_proj": {"wfq": fq_stacked(lp["ssm"]["in_proj"]["w"], "ssm_in")},
-                "out_proj": {"wfq": fq_stacked(lp["ssm"]["out_proj"]["w"], "ssm_out")},
-            }
+                "in_proj": stacked(lp["ssm"]["in_proj"]["w"], "ssm_in"),
+                "out_proj": stacked(lp["ssm"]["out_proj"]["w"], "ssm_out")}
         if cfg.family == "moe" and "w" in lp["moe"]["router"]:
             moev: dict[str, Any] = {
-                "router": {"wfq": fq_stacked(lp["moe"]["router"]["w"], "router")}}
+                "router": stacked(lp["moe"]["router"]["w"], "router")}
             if "shared_in" in lp["moe"]:
-                moev["shared_in"] = {
-                    "wfq": fq_stacked(lp["moe"]["shared_in"]["w"], "shared_in")}
-                moev["shared_out"] = {
-                    "wfq": fq_stacked(lp["moe"]["shared_out"]["w"], "shared_out")}
+                moev["shared_in"] = stacked(lp["moe"]["shared_in"]["w"],
+                                            "shared_in")
+                moev["shared_out"] = stacked(lp["moe"]["shared_out"]["w"],
+                                             "shared_out")
             lov["moe"] = moev
         if lov:
             ov["layers"] = lov
         return ov
 
-    return jax.vmap(one_profile)(jnp.asarray(table))
+    return {"image_of": jnp.asarray(image_of.reshape(-1), jnp.int32),
+            "images": jax.vmap(one_image)(jnp.asarray(w_rows, jnp.int32))}
+
+
+def decode_image(prequant: dict, pid: jax.Array) -> dict:
+    """Profile ``pid``'s weight image (traced ``pid``) from
+    :func:`prequant_decode_weights`."""
+    img = prequant["image_of"][pid]
+    return jax.tree.map(lambda a: a[img], prequant["images"])
 
 
 def overlay_params(base: dict, overlay: dict) -> dict:
@@ -1026,8 +1053,7 @@ def decode_segment(params: dict, cfg: ModelConfig, table: jax.Array,
         # per-layer KV precision row, gathered by the step's (traced)
         # profile id — like bits_row, a schedule switch never retraces
         ks = None if kv_table is None else kv_table[pid]
-        p_step = overlay_params(params,
-                                jax.tree.map(lambda a: a[pid], prequant))
+        p_step = overlay_params(params, decode_image(prequant, pid))
         logits, cch = decode_step(p_step, cfg, bits_row, tok[:, None], pos, cch,
                                   row_valid=live, paged_backend=paged_backend,
                                   kv_sched=ks)
@@ -1355,8 +1381,7 @@ def decode_segment_spec(params: dict, cfg: ModelConfig, table: jax.Array,
         tok, pos, rem, qta, ok, hist, cch = carry
         live = (rem > 0) & (qta > 0)
         bits_row = table[pid]
-        p_step = overlay_params(params,
-                                jax.tree.map(lambda a: a[pid], prequant))
+        p_step = overlay_params(params, decode_image(prequant, pid))
         if draft_fn is not None:
             prop = jnp.asarray(draft_fn(hist, tok), jnp.int32)
         else:

@@ -238,6 +238,33 @@ class Request:
     deadline_ms: Optional[float] = None
 
 
+class _BoundWeights:
+    """A jitted serving primitive whose leading arguments — the server's
+    lifetime weights — are bound here instead of closed over.
+
+    ``jax.jit`` embeds a closed-over array in the executable as a literal
+    constant: at full width that is gigabytes of HLO to build and compile.
+    Bound weights stay device buffers passed at every dispatch, which costs
+    one flatten of a pytree of a few dozen leaves. ``donate_argnums``
+    index the unbound arguments.
+    """
+
+    def __init__(self, fn, weights: tuple, donate_argnums: tuple = ()):
+        n = len(weights)
+        self.jitted = jax.jit(
+            fn, donate_argnums=tuple(n + i for i in donate_argnums))
+        self.weights = weights
+
+    def __call__(self, *args):
+        return self.jitted(*self.weights, *args)
+
+    def lower(self, *args):
+        return self.jitted.lower(*self.weights, *args)
+
+    def _cache_size(self) -> int:
+        return self.jitted._cache_size()
+
+
 class AdaptiveServer:
     """Adaptive inference engine: jitted serving entry points over one model.
 
@@ -245,15 +272,15 @@ class AdaptiveServer:
     ``_decode`` (stepwise oracle), ``_generate`` (fused whole-generation
     scan), and the continuous-batching primitives ``_segment`` / ``_admit``
     (+ paged variants) shared by every :class:`~repro.serving.scheduler.
-    ContinuousScheduler` built on top — plus the per-profile prequantized
-    weight images. Profile adaptivity is bits-as-data: ``profile_id`` and
+    ContinuousScheduler` built on top — plus the prequantized decode
+    weight images (one per distinct weight-width row of the profiles). Profile adaptivity is bits-as-data: ``profile_id`` and
     per-step schedules are traced int32 inputs, so switching profiles never
     recompiles (the paper's runtime configuration word).
 
     Args:
         cfg: model architecture.
         params: parameter pytree (fixed for the server's lifetime — the
-            prequant images and closed-over executables assume it).
+            prequant images and the executables bound to it assume it).
         engine: merged :class:`AdaptiveEngine` (profile family + bits table).
         serving: :class:`ServingConfig` deployment knobs.
         manager: optional :class:`ProfileManager`; ``None`` pins profile 0.
@@ -372,39 +399,38 @@ class AdaptiveServer:
         self.paged_backend = pb
 
         # params / prequant are server-lifetime constants: the continuous
-        # primitives close over them so a dispatch only flattens the small
-        # slot-pool carry (schedule, tok, pos, caches, remaining) instead of
-        # re-processing the full parameter pytree every segment — per-call
-        # python overhead is what continuous batching lives or dies by
-        def segment_fn(schedule, tok, pos, caches, remaining, fault_step):
+        # primitives take them as leading arguments that _BoundWeights binds,
+        # never as closed-over constants (see _BoundWeights)
+        def segment_fn(params, prequant, schedule, tok, pos, caches,
+                       remaining, fault_step):
             # fault_step [B] is DATA (normally all −1): the chaos machinery's
             # NaN-injection operand plus the per-row finite-check flag ride
             # the one pool-lifetime segment executable — detection and
             # injection never add a dispatch or a recompile
-            return T.decode_segment(self.params, cfg, jnp.asarray(table),
+            return T.decode_segment(params, cfg, jnp.asarray(table),
                                     schedule, tok, pos, caches, remaining,
-                                    prequant=self._prequant,
+                                    prequant=prequant,
                                     paged_backend=self.paged_backend,
                                     fault_step=fault_step,
                                     kv_table=kv_table)
 
-        def segment_spec_fn(schedule, hist, spec_on, tok, pos, caches,
-                            remaining, quota, fault_step):
+        def segment_spec_fn(params, prequant, schedule, hist, spec_on, tok,
+                            pos, caches, remaining, quota, fault_step):
             # speculative pool-lifetime segment: len(schedule) draft/verify
             # windows; hist/spec_on/quota are per-dispatch DATA operands
             # (host token history, per-class opt-out, quantum in accepted
             # tokens) — same zero-recompile contract as the greedy segment
-            return T.decode_segment_spec(self.params, cfg, jnp.asarray(table),
+            return T.decode_segment_spec(params, cfg, jnp.asarray(table),
                                          schedule, tok, pos, caches,
                                          remaining, quota=quota, hist0=hist,
                                          spec_on=spec_on,
-                                         prequant=self._prequant,
+                                         prequant=prequant,
                                          paged_backend=self.paged_backend,
                                          fault_step=fault_step,
                                          draft_k=serving.draft_k,
                                          draft_fn=self.draft_fn)
 
-        def admit_fn(profile_id, batch, slots_idx, tok, pos, caches):
+        def admit_fn(params, profile_id, batch, slots_idx, tok, pos, caches):
             # one admission wave = one dispatch: ragged prefill of every
             # waiting request (left-padded to a shared pow2 bucket,
             # ``prompt_len`` as data) + on-device first-token argmax + scatter
@@ -416,7 +442,7 @@ class AdaptiveServer:
             # the new request's attention window.
             bits = jnp.asarray(table)[profile_id]
             ks = None if kv_table is None else kv_table[profile_id]
-            logits, rows = T.prefill(self.params, cfg, bits, batch,
+            logits, rows = T.prefill(params, cfg, bits, batch,
                                      serving.slots, kv_bits=serving.kv_bits,
                                      kv_sched=ks)
             tok0 = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -466,8 +492,8 @@ class AdaptiveServer:
         self._collect_masters = self.masters_mode and bool(
             self.prefix_sharing or self.chunk_tokens)
 
-        def admit_paged_fn(profile_id, batch, slots_idx, dest, tok, pos,
-                           caches):
+        def admit_paged_fn(params, profile_id, batch, slots_idx, dest, tok,
+                           pos, caches):
             # paged admission wave: one ragged prefill into transient dense
             # rows, then one scatter of those rows into the block pool at
             # the host-chosen physical ids. ``dest[j, l]`` is the write
@@ -479,7 +505,7 @@ class AdaptiveServer:
             # ``token_idx`` left by the block's previous owner.
             bits = jnp.asarray(table)[profile_id]
             ks = None if kv_table is None else kv_table[profile_id]
-            out = T.prefill(self.params, cfg, bits, batch, self.slots_p,
+            out = T.prefill(params, cfg, bits, batch, self.slots_p,
                             kv_bits=serving.kv_bits,
                             return_raw_kv=self._collect_masters,
                             kv_sched=ks)
@@ -500,9 +526,9 @@ class AdaptiveServer:
                     pos.at[slots_idx].set(plen, mode="drop"),
                     caches)
 
-        def _admit_shared_body(profile_id, batch, slots_idx, dest, bt_rows,
-                               kpre, vpre, ka, va, prefix_len, tok, pos,
-                               caches):
+        def _admit_shared_body(params, profile_id, batch, slots_idx, dest,
+                               bt_rows, kpre, vpre, ka, va, prefix_len, tok,
+                               pos, caches):
             # shared-prefix admission wave: continuation prefill over the
             # suffixes only (prefix KV replayed from masters / pool
             # blocks), then the same block scatter — with ``dest``
@@ -514,7 +540,7 @@ class AdaptiveServer:
             bits = jnp.asarray(table)[profile_id]
             ks = None if kv_table is None else kv_table[profile_id]
             out = T.prefill_extend(
-                self.params, cfg, bits, batch, self.slots_p,
+                params, cfg, bits, batch, self.slots_p,
                 kv_bits=serving.kv_bits, prefix_k=kpre, prefix_v=vpre,
                 prefix_len=prefix_len, prefix_k_amax=ka, prefix_v_amax=va,
                 return_raw_kv=self._collect_masters, kv_sched=ks)
@@ -532,8 +558,9 @@ class AdaptiveServer:
                     pos.at[slots_idx].set(plen, mode="drop"),
                     caches)
 
-        def admit_shared_pool_fn(profile_id, batch, slots_idx, dest, bt_rows,
-                                 pre_bids, prefix_len, tok, pos, caches):
+        def admit_shared_pool_fn(params, profile_id, batch, slots_idx, dest,
+                                 bt_rows, pre_bids, prefix_len, tok, pos,
+                                 caches):
             # bf16 variant: the shared pool blocks ARE the masters — gather
             # the prefix KV straight from them (zero duplicated storage)
             pool = caches["kv"]
@@ -544,8 +571,8 @@ class AdaptiveServer:
                 return g.reshape(cfg.n_layers, a, pb * x.shape[2],
                                  *x.shape[3:]).astype(jnp.float32)
 
-            return _admit_shared_body(profile_id, batch, slots_idx, dest,
-                                      bt_rows, gather(pool.k),
+            return _admit_shared_body(params, profile_id, batch, slots_idx,
+                                      dest, bt_rows, gather(pool.k),
                                       gather(pool.v), None, None,
                                       prefix_len, tok, pos, caches)
 
@@ -561,8 +588,8 @@ class AdaptiveServer:
         self._prefill = jax.jit(prefill_fn)
         self._decode = jax.jit(decode_fn,
                                donate_argnums=(4,))        # stepwise baseline
-        # per-profile weight images, materialized once per server (params and
-        # the profile table are fixed for its lifetime)
+        # decode weight images, materialized once per server (params and the
+        # profile table are fixed for its lifetime)
         self._prequant = jax.jit(
             lambda p: T.prequant_decode_weights(p, cfg, jnp.asarray(table))
         )(params)
@@ -576,29 +603,34 @@ class AdaptiveServer:
         # A speculative server's ONE pool-lifetime segment executable IS the
         # spec variant — never both, so the single-_segment invariant holds
         # in either mode (SchedulerAudit.assert_single_segment)
+        weights = (self.params, self._prequant)
         if serving.speculate:
-            self._segment = jax.jit(segment_spec_fn,
-                                    donate_argnums=(3, 4, 5))
+            self._segment = _BoundWeights(segment_spec_fn, weights,
+                                          donate_argnums=(3, 4, 5))
         else:
-            self._segment = jax.jit(segment_fn, donate_argnums=(1, 2, 3))
-        self._admit = jax.jit(admit_fn, donate_argnums=(3, 4, 5))
+            self._segment = _BoundWeights(segment_fn, weights,
+                                          donate_argnums=(1, 2, 3))
+        self._admit = _BoundWeights(admit_fn, (params,),
+                                    donate_argnums=(3, 4, 5))
         # paged continuous-batching primitives: same sharing story as above
         # (compiled once per server; the scheduler owns the donated pool)
-        self._admit_paged = jax.jit(admit_paged_fn, donate_argnums=(4, 5, 6))
+        self._admit_paged = _BoundWeights(admit_paged_fn, (params,),
+                                          donate_argnums=(4, 5, 6))
         # shared-prefix admissions and chunked-prefill continuations share
         # the same continuation executable
         if not (self.prefix_sharing or self.chunk_tokens):
             self._admit_shared = None
         elif not self.masters_mode:
-            self._admit_shared = jax.jit(admit_shared_pool_fn,
-                                         donate_argnums=(7, 8, 9))
+            self._admit_shared = _BoundWeights(admit_shared_pool_fn,
+                                               (params,),
+                                               donate_argnums=(7, 8, 9))
         else:
             # master-backed variant: prefix replayed from full-precision
             # registry masters — mandatory at int KV (the pool's int8 rows
             # were quantized on the *owner's* per-row grid and are not
             # bit-shareable), opt-in at kv16 via ``kv16_masters``
-            self._admit_shared = jax.jit(_admit_shared_body,
-                                         donate_argnums=(10, 11, 12))
+            self._admit_shared = _BoundWeights(_admit_shared_body, (params,),
+                                               donate_argnums=(10, 11, 12))
         self._clear_rows = jax.jit(clear_rows_fn, donate_argnums=(1,))
         # preemption restore: a suspended row re-admits by replaying its own
         # processed tokens as the continuation prefix — always from the
@@ -625,8 +657,9 @@ class AdaptiveServer:
         elif self.masters_mode and self._admit_shared is not None:
             self._admit_restore = self._admit_shared
         else:
-            self._admit_restore = jax.jit(_admit_shared_body,
-                                          donate_argnums=(10, 11, 12))
+            self._admit_restore = _BoundWeights(_admit_shared_body,
+                                                (params,),
+                                                donate_argnums=(10, 11, 12))
 
     def _scatter_blocks(self, pool, rows, dest, sidx, bt_rows=None):
         """Scatter dense admission rows into the paged pool (traced helper).

@@ -1,0 +1,70 @@
+"""Compile rehearsals for a v5e chip: the paged-attention kernels of the
+serving path at granite-3-2b's attention geometry (8 KV heads, 4 query heads
+per KV head, head dim 64, 16-token blocks), compiled for a described chip
+that is not attached. The TPU compiler refuses here what interpret mode never
+checks (block shapes off the (8, 128) tiling, unsupported vector relayouts),
+so these guard every change to the kernels at no chip time.
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process may load the TPU library at a time, and every test worker
+imports this file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.paged_attention import (paged_attention_pallas,
+                                           paged_attention_pallas_multi)
+
+B, HKV, HG, D, BS, N_BLOCKS, N_LBLK, W = 8, 8, 4, 64, 16, 256, 16, 5
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _pool_args(sharding, bits, q_shape, scale_shape):
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+
+    dk = D // 2 if bits == 4 else D
+    pool_dt = jnp.bfloat16 if bits == 16 else jnp.int8
+    return (s(q_shape, jnp.float32), s((N_BLOCKS, BS, HKV, dk), pool_dt),
+            s((N_BLOCKS, BS, HKV, dk), pool_dt), s(scale_shape, jnp.float32),
+            s(scale_shape, jnp.float32), s((N_BLOCKS, BS), jnp.int32),
+            s((B, N_LBLK), jnp.int32), s((B,), jnp.int32))
+
+
+def _compiled_hlo(fn, args) -> str:
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("bits", [16, 8, 4])
+def test_paged_attention_compiles_for_v5e(one_chip, bits):
+    args = _pool_args(one_chip, bits, (B, HKV, HG, D), (B, HKV))
+    hlo = _compiled_hlo(
+        lambda *a: paged_attention_pallas(*a, bits=bits), args)
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("bits", [16, 8])
+def test_paged_attention_multi_compiles_for_v5e(one_chip, bits):
+    args = _pool_args(one_chip, bits, (B, W, HKV, HG, D), (B, W, HKV))
+    hlo = _compiled_hlo(
+        lambda *a: paged_attention_pallas_multi(*a, bits=bits), args)
+    assert "tpu_custom_call" in hlo
