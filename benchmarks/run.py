@@ -7,10 +7,8 @@ Prints ``name,us_per_call,derived`` CSV lines (harness contract).
   fig3_<profile>     — accuracy-vs-energy Pareto points
   fig4_adaptive      — merged-engine overhead + battery simulation
   kernel_*           — Pallas kernel microbenches (interpret-validated)
-  roofline_<cell>    — dry-run roofline step-time estimates (if artifacts exist)
 
-Heavy QAT results are cached under artifacts/repro/ (delete to retrain);
-roofline rows appear after ``python -m repro.launch.dryrun --all``.
+Heavy QAT results are cached under artifacts/repro/ (delete to retrain).
 """
 from __future__ import annotations
 
@@ -47,17 +45,6 @@ def main() -> None:
     # --- serving decode loop (fused scan vs per-token host loop) ---
     from benchmarks import serving_bench
     rows.extend(serving_bench.run(serving_bench.QUICK_POINTS, iters=2))
-
-    # --- roofline (from dry-run artifacts when present) ---
-    try:
-        from benchmarks import roofline
-        for r in roofline.table("pod1"):
-            rows.append((f"roofline_{r['arch']}_{r['shape']}",
-                         r["t_step_s"] * 1e6,
-                         f"dominant={r['dominant'].split('_')[0]};"
-                         f"useful_ratio={r['useful_ratio']:.2f}"))
-    except Exception as e:  # artifacts absent → still a valid bench run
-        rows.append(("roofline", 0.0, f"unavailable:{type(e).__name__}"))
 
     print("name,us_per_call,derived")
     for name, us, derived in rows:

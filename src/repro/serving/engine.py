@@ -40,6 +40,7 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.engine import AdaptiveEngine
 from repro.core.manager import ProfileManager, ProfileStats
@@ -247,6 +248,9 @@ class _BoundWeights:
     Bound weights stay device buffers passed at every dispatch, which costs
     one flatten of a pytree of a few dozen leaves. ``donate_argnums``
     index the unbound arguments.
+
+    Each dispatch is a ``serve.dispatch.<fn>`` profiler span: the host
+    time to flatten the arguments and enqueue the executable.
     """
 
     def __init__(self, fn, weights: tuple, donate_argnums: tuple = ()):
@@ -254,9 +258,11 @@ class _BoundWeights:
         self.jitted = jax.jit(
             fn, donate_argnums=tuple(n + i for i in donate_argnums))
         self.weights = weights
+        self.span = "serve.dispatch." + fn.__name__.lstrip("_")
 
     def __call__(self, *args):
-        return self.jitted(*self.weights, *args)
+        with TraceAnnotation(self.span):
+            return self.jitted(*self.weights, *args)
 
     def lower(self, *args):
         return self.jitted.lower(*self.weights, *args)
@@ -631,7 +637,8 @@ class AdaptiveServer:
             # bit-shareable), opt-in at kv16 via ``kv16_masters``
             self._admit_shared = _BoundWeights(_admit_shared_body, (params,),
                                                donate_argnums=(10, 11, 12))
-        self._clear_rows = jax.jit(clear_rows_fn, donate_argnums=(1,))
+        self._clear_rows = _BoundWeights(clear_rows_fn, (),
+                                         donate_argnums=(1,))
         # preemption restore: a suspended row re-admits by replaying its own
         # processed tokens as the continuation prefix — always from the
         # host-side masters its eviction snapshotted (the row's blocks were

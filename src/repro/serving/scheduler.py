@@ -101,15 +101,28 @@ after an exponential backoff, up to ``retry_budget`` attempts. Injected
 chaos (:class:`~repro.serving.faults.FaultSchedule`) and the audit
 (:meth:`check`, the ``paranoid`` mode) make all of this testable
 deterministically.
+
+**Tracing.** The host path is named in ``jax.profiler`` spans, which a
+running profiler records on the device trace's clock (and which cost a
+few hundred nanoseconds each when none runs): ``serve.step`` (one round),
+``serve.submit``, ``serve.admit``, ``serve.segment``, ``serve.flush`` with
+``serve.flush.wait`` (the host blocked on the device's tokens) inside it,
+and ``serve.dispatch.<fn>`` per executable call (the server's). A paged
+pool also counts, per decode segment over its live rows, the KV blocks
+the rows hold (``kv_blocks_reserved``) against those their contexts fill
+(``kv_blocks_written``); :meth:`ContinuousScheduler.paged_stats` returns
+both.
 """
 from __future__ import annotations
 
 import dataclasses
 import time
+from functools import partial
 from typing import Callable, Optional
 
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation, annotate_function
 
 from repro.models import transformer as T
 from .engine import AdaptiveServer, Request, RequestStatus, _next_pow2
@@ -211,6 +224,9 @@ class ContinuousScheduler:
             self._slot_blocks: list = [None] * nslots  # (private_ids, entry)
             self._prefix_keys: dict[int, list[bytes]] = {}
             self.peak_used_blocks = 0
+            # summed over each decode segment's live rows (_count_kv)
+            self.kv_blocks_reserved = 0
+            self.kv_blocks_written = 0
             # chunked prefill: long cold prompts (and registry hits with a
             # long unique suffix) prefill in block-aligned chunks that
             # interleave with decode segments instead of one monolithic
@@ -310,6 +326,20 @@ class ContinuousScheduler:
             blocks, cache=(self.registry.covered(blocks)
                            if self.registry is not None else ()))
 
+    def _count_kv(self, slot: int, rid: int) -> None:
+        """Count one live row of a decode segment that has run: the blocks
+        it holds (private plus mapped shared) into ``kv_blocks_reserved``,
+        and the blocks its context fills (``prompt + max_new − remaining``
+        positions, at most what it holds) into ``kv_blocks_written``."""
+        blocks, reg = self._slot_blocks[slot]
+        held = len(blocks)
+        if reg is not None and reg.block_ids is not None:
+            held += reg.n_tokens // self.block_size
+        req = self._reqs[rid]
+        ctx = len(req.tokens) + req.max_new - int(self.remaining[slot])
+        self.kv_blocks_reserved += held
+        self.kv_blocks_written += min(held, -(-ctx // self.block_size))
+
     def paged_stats(self) -> dict:
         """Block-pool occupancy + prefix-registry counters (bench JSON).
 
@@ -320,7 +350,9 @@ class ContinuousScheduler:
         allocatable capacity AND resurrectable cache, the retired-block
         LRU), and ``free_blocks`` (neither). The three always partition
         the pool — the bench asserts it as a cross-check between the
-        refcount, LRU, and free-list bookkeeping.
+        refcount, LRU, and free-list bookkeeping. ``kv_blocks_reserved``
+        and ``kv_blocks_written`` are the monotone KV counters of
+        :meth:`_count_kv`.
         """
         if not self.paged:
             return {"paged": False,
@@ -338,6 +370,8 @@ class ContinuousScheduler:
             "free_blocks": self.allocator.free_blocks,
             "preemptions": self.preemptions,
             "resumes": self.resumes,
+            "kv_blocks_reserved": self.kv_blocks_reserved,
+            "kv_blocks_written": self.kv_blocks_written,
             "kv_bytes": T.cache_bytes(self._caches),
             "registry_bytes": 0,
         }
@@ -350,6 +384,7 @@ class ContinuousScheduler:
         return out
 
     # ------------------------------------------------------------------ queue
+    @partial(annotate_function, name="serve.submit")
     def submit(self, request: Request) -> int:
         """Enqueue a request with the scheduling policy. Returns its id.
 
@@ -736,6 +771,7 @@ class ContinuousScheduler:
         return out
 
     # -------------------------------------------------------------- admission
+    @partial(annotate_function, name="serve.admit")
     def admit(self) -> int:
         """Fill free slots from the policy queue; returns #requests admitted.
 
@@ -1657,6 +1693,7 @@ class ContinuousScheduler:
         self._slot_spec[slot] = self.policy.bind_speculative(req)
 
     # --------------------------------------------------------------- decoding
+    @partial(annotate_function, name="serve.segment")
     def run_segment(self) -> None:
         """One decode segment over the pool: plan ``quantum`` steps against
         the live rows, dispatch the fused scan, distribute tokens, retire.
@@ -1712,6 +1749,8 @@ class ContinuousScheduler:
             n = int(min(self.remaining[slot], q))
             entry["rows"].append((slot, rid, n))
             self.remaining[slot] -= n
+            if self.paged and n:
+                self._count_kv(slot, rid)
             if self.remaining[slot] == 0:                # retire → refillable
                 self.slot_req[slot] = None
                 self._slot_crit[slot] = False
@@ -1803,7 +1842,8 @@ class ContinuousScheduler:
                      if self.slot_req[s] is not None],
             "completes": []})
 
-    def _flush_spec(self, e: dict, arr: np.ndarray, names) -> None:
+    def _flush_spec(self, e: dict, arr: np.ndarray,
+                    okarr: Optional[np.ndarray], names) -> None:
         """Materialize one speculative segment entry (the ``keep=0`` sync
         point): distribute each window's delivered prefix, bill the ledger
         the tokens actually delivered (the dispatch plan was provisional —
@@ -1811,10 +1851,8 @@ class ContinuousScheduler:
         whose budget hit zero and hand their blocks back. Rows whose
         verify windows went non-finite route to quarantine exactly like
         greedy segments."""
-        # repro: allow(host-sync) the flush boundary IS the sync point
+        # repro: allow(host-sync) ready with toks, materialized by the caller
         ms = np.asarray(e["ms"])                          # [B, n_iter]
-        # repro: allow(host-sync) flush-boundary sync, same as ms
-        okarr = np.asarray(e["ok"]) if e.get("ok") is not None else None
         mgr = self.srv.manager
         sched = e["sched"]
         n_iter = ms.shape[1]
@@ -1843,6 +1881,8 @@ class ContinuousScheduler:
                     and rid not in self._nf_rows:
                 self._nf_rows.append(rid)
             self.remaining[slot] -= len(delivered)
+            if self.paged and delivered:
+                self._count_kv(slot, rid)
             if self.remaining[slot] == 0 and delivered:
                 self.slot_req[slot] = None               # retire → refill
                 self._slot_crit[slot] = False
@@ -1860,6 +1900,7 @@ class ContinuousScheduler:
                     self.registry.release(reg)
                 self._slot_blocks[slot] = None
 
+    @partial(annotate_function, name="serve.flush")
     def _flush(self, keep: int = 0) -> None:
         """Materialize in-flight token blocks into per-request results.
 
@@ -1873,6 +1914,7 @@ class ContinuousScheduler:
         host: each segment entry carries its per-row finite-check flags,
         and a live row that went non-finite is routed to quarantine
         (:meth:`_process_quarantine`) instead of completing.
+        ``serve.flush.wait`` spans the host blocked on each entry's arrays.
         """
         if self.faults is not None and len(self._inflight) > keep:
             s = self.faults.flush_stall(self._flush_idx)
@@ -1883,8 +1925,12 @@ class ContinuousScheduler:
         drained = len(self._inflight) > keep
         while len(self._inflight) > keep:
             e = self._inflight.pop(0)
-            # repro: allow(host-sync) the flush boundary IS the sync point
-            arr = np.asarray(e["toks"])                  # blocks until ready
+            with TraceAnnotation("serve.flush.wait"):
+                # repro: allow(host-sync) the flush boundary IS the sync point
+                arr = np.asarray(e["toks"])              # blocks until ready
+                # repro: allow(host-sync) flush-boundary sync, same as toks
+                okarr = (np.asarray(e["ok"])
+                         if e.get("ok") is not None else None)
             if e["kind"] == "admit":
                 for j, rid in e["rows"]:
                     res = self.results[rid]
@@ -1900,11 +1946,8 @@ class ContinuousScheduler:
                         except ValueError:
                             pass         # max_new == 1: never went live
             elif e["kind"] == "spec":
-                self._flush_spec(e, arr, names)
+                self._flush_spec(e, arr, okarr, names)
             else:
-                # repro: allow(host-sync) flush-boundary sync, same as toks
-                okarr = (np.asarray(e["ok"])
-                         if e.get("ok") is not None else None)
                 for slot, rid, n in e["rows"]:
                     res = self.results[rid]
                     res["tokens"].extend(arr[slot, :n].tolist())
@@ -1931,6 +1974,7 @@ class ContinuousScheduler:
             self.durable.on_flush()      # crash-point / consistency-cut mark
 
     # ------------------------------------------------------------------ drive
+    @partial(annotate_function, name="serve.step")
     def step(self) -> bool:
         """One engine round: retire deadline/cancel/fault casualties, then
         admit and run one segment (one kept in flight). Returns False once

@@ -3,13 +3,15 @@ serving path at granite-3-2b's attention geometry (8 KV heads, 4 query heads
 per KV head, head dim 64, 16-token blocks), compiled for a described chip
 that is not attached. The TPU compiler refuses here what interpret mode never
 checks (block shapes off the (8, 128) tiling, unsupported vector relayouts),
-so these guard every change to the kernels at no chip time.
+so these guard every change to the kernels at no chip time. They also pin
+the kernel's op name, which the benchmark's trace reduction matches.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process may load the TPU library at a time, and every test worker
 imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -54,12 +56,19 @@ def _compiled_hlo(fn, args) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+def _kernel_ops(hlo: str) -> list[str]:
+    """Names of the compiled Mosaic kernel calls (``%<name> = ...``)."""
+    return re.findall(r"%(\S+) = .*custom_call_target=\"tpu_custom_call\"",
+                      hlo)
+
+
 @pytest.mark.parametrize("bits", [16, 8, 4])
 def test_paged_attention_compiles_for_v5e(one_chip, bits):
     args = _pool_args(one_chip, bits, (B, HKV, HG, D), (B, HKV))
     hlo = _compiled_hlo(
         lambda *a: paged_attention_pallas(*a, bits=bits), args)
-    assert "tpu_custom_call" in hlo
+    ops = _kernel_ops(hlo)
+    assert ops and all(o.startswith("paged_attention_pallas") for o in ops)
 
 
 @pytest.mark.parametrize("bits", [16, 8])
@@ -67,4 +76,5 @@ def test_paged_attention_multi_compiles_for_v5e(one_chip, bits):
     args = _pool_args(one_chip, bits, (B, W, HKV, HG, D), (B, W, HKV))
     hlo = _compiled_hlo(
         lambda *a: paged_attention_pallas_multi(*a, bits=bits), args)
-    assert "tpu_custom_call" in hlo
+    ops = _kernel_ops(hlo)
+    assert ops and all(o.startswith("paged_attention_pallas") for o in ops)
