@@ -110,8 +110,9 @@ few hundred nanoseconds each when none runs): ``serve.step`` (one round),
 and ``serve.dispatch.<fn>`` per executable call (the server's). A paged
 pool also counts, per decode segment over its live rows, the KV blocks
 the rows hold (``kv_blocks_reserved``) against those their contexts fill
-(``kv_blocks_written``); :meth:`ContinuousScheduler.paged_stats` returns
-both.
+(``kv_blocks_written``) and against their whole block tables
+(``kv_blocks_table``, which a kernel visiting every logical block reads);
+:meth:`ContinuousScheduler.paged_stats` returns all three.
 """
 from __future__ import annotations
 
@@ -227,6 +228,7 @@ class ContinuousScheduler:
             # summed over each decode segment's live rows (_count_kv)
             self.kv_blocks_reserved = 0
             self.kv_blocks_written = 0
+            self.kv_blocks_table = 0
             # chunked prefill: long cold prompts (and registry hits with a
             # long unique suffix) prefill in block-aligned chunks that
             # interleave with decode segments instead of one monolithic
@@ -329,8 +331,9 @@ class ContinuousScheduler:
     def _count_kv(self, slot: int, rid: int) -> None:
         """Count one live row of a decode segment that has run: the blocks
         it holds (private plus mapped shared) into ``kv_blocks_reserved``,
-        and the blocks its context fills (``prompt + max_new − remaining``
-        positions, at most what it holds) into ``kv_blocks_written``."""
+        the blocks its context fills (``prompt + max_new − remaining``
+        positions, at most what it holds) into ``kv_blocks_written``, and
+        its block table's length into ``kv_blocks_table``."""
         blocks, reg = self._slot_blocks[slot]
         held = len(blocks)
         if reg is not None and reg.block_ids is not None:
@@ -339,6 +342,7 @@ class ContinuousScheduler:
         ctx = len(req.tokens) + req.max_new - int(self.remaining[slot])
         self.kv_blocks_reserved += held
         self.kv_blocks_written += min(held, -(-ctx // self.block_size))
+        self.kv_blocks_table += self.n_lblk
 
     def paged_stats(self) -> dict:
         """Block-pool occupancy + prefix-registry counters (bench JSON).
@@ -350,9 +354,9 @@ class ContinuousScheduler:
         allocatable capacity AND resurrectable cache, the retired-block
         LRU), and ``free_blocks`` (neither). The three always partition
         the pool — the bench asserts it as a cross-check between the
-        refcount, LRU, and free-list bookkeeping. ``kv_blocks_reserved``
-        and ``kv_blocks_written`` are the monotone KV counters of
-        :meth:`_count_kv`.
+        refcount, LRU, and free-list bookkeeping. ``kv_blocks_reserved``,
+        ``kv_blocks_written`` and ``kv_blocks_table`` are the monotone KV
+        counters of :meth:`_count_kv`.
         """
         if not self.paged:
             return {"paged": False,
@@ -372,6 +376,7 @@ class ContinuousScheduler:
             "resumes": self.resumes,
             "kv_blocks_reserved": self.kv_blocks_reserved,
             "kv_blocks_written": self.kv_blocks_written,
+            "kv_blocks_table": self.kv_blocks_table,
             "kv_bytes": T.cache_bytes(self._caches),
             "registry_bytes": 0,
         }

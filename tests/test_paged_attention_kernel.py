@@ -3,9 +3,13 @@
 The load-bearing properties of the serving hot-path rewrite:
 
 * the kernel (interpret mode) matches the gather-view ``decode_attention``
-  oracle to float precision at kv16 and kv8, across block-boundary cache
-  lengths, fragmented/out-of-order block tables, and dead rows (both the
-  ``-1`` and the ``>= n_blocks`` unmapped sentinels);
+  oracle to float precision at kv16, kv8 and kv4, single-query and in a
+  W = 5 window, across contexts ending on block and chunk (pages per grid
+  step) boundaries, a full table, a wrapped ring, sliding windows,
+  fragmented/out-of-order block tables, and dead rows (both the ``-1`` and
+  the ``>= n_blocks`` unmapped sentinels);
+* pages past a row's live bound are neither fetched nor computed: NaN
+  there leaves the output finite and equal to the oracle's with zeros;
 * the ``pallas`` segment backend is token-identical to the ``gather``
   backend / solo generation at kv16 and kv8 — including shared-prefix
   copy-on-write rows — while materializing **no** ``[B, n_lblk*bs]`` view
@@ -22,8 +26,11 @@ from repro.analysis.jaxpr_check import has_adjacent_dims
 from repro.configs import get_smoke
 from repro.core.engine import AdaptiveEngine, QuantIndex
 from repro.core.profiles import paper_profiles
+from repro.core.qtypes import pack_int4
 from repro.kernels import ref
-from repro.kernels.paged_attention import paged_attention_pallas
+from repro.kernels.paged_attention import (pages_per_step,
+                                           paged_attention_pallas,
+                                           paged_attention_pallas_multi)
 from repro.models import transformer as T
 from repro.serving.engine import AdaptiveServer, Request, ServingConfig
 from repro.serving.scheduler import ContinuousScheduler
@@ -56,69 +63,143 @@ def _solo_tokens(parts, req, kv_bits=16, slots=64):
 # kernel vs gather-view oracle (interpret mode)
 # ---------------------------------------------------------------------------
 
-def _pool_case(seed, lengths, *, n_blocks=16, bs=8, n_lblk=4, hkv=2, hg=2,
-               d=16, kv_bits=16, dead_sentinels=()):
+BS, N_LBLK = 16, 20                 # 320 table slots
+P = pages_per_step(BS, N_LBLK)      # pages per grid step: chunks P*BS long
+CAP = N_LBLK * BS
+# contexts on block and chunk boundaries, and one holding the whole table
+LENGTHS = (BS - 1, BS, BS + 1, 2 * BS + 1, P * BS - 1, P * BS, P * BS + 1,
+           CAP)
+WRAPPED = (CAP + 37,)               # ring positions past the table
+
+
+def _pool_case(seed, lengths, *, n_blocks=128, bs=BS, n_lblk=N_LBLK, hkv=2,
+               hg=2, d=16, kv_bits=16, w=1, wrapped=(), dead_sentinels=(),
+               map_all=False):
     """Fragmented paged state: per-row out-of-order physical blocks, cache
-    lengths straddling block boundaries, optional dead rows whose tables
-    hold only unmapped sentinels."""
+    lengths straddling block and chunk boundaries, rows whose ring has
+    wrapped (every block mapped, ``token_idx`` holding each slot's latest
+    position), optional dead rows whose tables hold only unmapped
+    sentinels. Row ``r`` with context ``n`` decodes W queries at ``n - w
+    ..n - 1``. ``map_all`` maps every logical block of the live rows, the
+    blocks past the context too (empty: ``token_idx`` −1). At ``w > 1`` q
+    is ``[B, W, Hkv, Hg, D]`` and the scales ``[B, W, Hkv]`` ladders."""
     rng = np.random.default_rng(seed)
-    b = len(lengths) + len(dead_sentinels)
-    q = jnp.asarray(rng.normal(size=(b, hkv, hg, d)), jnp.float32)
-    if kv_bits == 8:
-        kp = jnp.asarray(rng.integers(-127, 128, (n_blocks, bs, hkv, d)),
-                         jnp.int8)
-        vp = jnp.asarray(rng.integers(-127, 128, (n_blocks, bs, hkv, d)),
-                         jnp.int8)
-        ks = jnp.asarray(rng.uniform(0.01, 0.1, (b, hkv)), jnp.float32)
-        vs = jnp.asarray(rng.uniform(0.01, 0.1, (b, hkv)), jnp.float32)
+    b = len(lengths) + len(wrapped) + len(dead_sentinels)
+    lead = (b,) if w == 1 else (b, w)
+    q = jnp.asarray(rng.normal(size=(*lead, hkv, hg, d)), jnp.float32)
+    pool = (n_blocks, bs, hkv, d)
+    if kv_bits == 16:
+        kp, vp = (jnp.asarray(rng.normal(size=pool), jnp.float32)
+                  .astype(jnp.bfloat16) for _ in range(2))
+        ks = vs = jnp.ones((*lead, hkv), jnp.float32)
     else:
-        kp = jnp.asarray(rng.normal(size=(n_blocks, bs, hkv, d)),
-                         jnp.float32).astype(jnp.bfloat16)
-        vp = jnp.asarray(rng.normal(size=(n_blocks, bs, hkv, d)),
-                         jnp.float32).astype(jnp.bfloat16)
-        ks = vs = jnp.ones((b, hkv), jnp.float32)
-    # fragmented, out-of-order physical placement (one block per row+lblk)
-    perm = rng.permutation(n_blocks)
+        qmax = 127 if kv_bits == 8 else 7
+        kp, vp = (jnp.asarray(rng.integers(-qmax, qmax + 1, pool), jnp.int8)
+                  for _ in range(2))
+        if kv_bits == 4:
+            kp, vp = pack_int4(kp), pack_int4(vp)
+        ks, vs = (jnp.asarray(rng.uniform(0.01, 0.1, (*lead, hkv)),
+                              jnp.float32) for _ in range(2))
+    perm = iter(rng.permutation(n_blocks))
     tidx = np.full((n_blocks, bs), -1, np.int32)
     bt = np.full((b, n_lblk), n_blocks, np.int32)
     pos = np.zeros((b,), np.int32)
-    nxt = 0
+    cap = n_lblk * bs
     for r, ln in enumerate(lengths):
-        pos[r] = ln - 1                       # current token = last written
-        for lb in range(-(-ln // bs)):
-            p = int(perm[nxt]); nxt += 1
-            bt[r, lb] = p
-            nv = min(ln - lb * bs, bs)
-            tidx[p, :nv] = lb * bs + np.arange(nv)
+        pos[r] = ln - w
+        for lb in range(n_lblk if map_all else -(-ln // bs)):
+            bt[r, lb] = next(perm)
+            nv = min(max(ln - lb * bs, 0), bs)
+            tidx[bt[r, lb], :nv] = lb * bs + np.arange(nv)
+    for r, last in enumerate(wrapped, len(lengths)):
+        pos[r] = last - w + 1
+        for lb in range(n_lblk):
+            bt[r, lb] = next(perm)
+            v = lb * bs + np.arange(bs)
+            tidx[bt[r, lb]] = last - (last - v) % cap
     for i, sent in enumerate(dead_sentinels):
-        bt[len(lengths) + i, :] = sent        # -1 or n_blocks: both unmapped
+        bt[len(lengths) + len(wrapped) + i, :] = sent   # -1 / n_blocks
     return (q, kp, vp, ks, vs, jnp.asarray(tidx), jnp.asarray(bt),
             jnp.asarray(pos))
 
 
-@pytest.mark.parametrize("kv_bits", [16, 8])
+def _oracle(case, bits, window=0):
+    """``ref.paged_attention_ref``; a W-query window is W single-query
+    calls, query ``j`` at ``pos + j`` with ladder entry ``j``."""
+    q, kp, vp, ks, vs, tidx, bt, pos = case
+    if q.ndim == 4:
+        return ref.paged_attention_ref(*case, bits=bits, window=window)
+    return jnp.stack([ref.paged_attention_ref(
+        q[:, j], kp, vp, ks[:, j], vs[:, j], tidx, bt, pos + j, bits=bits,
+        window=window) for j in range(q.shape[1])], axis=1)
+
+
+@pytest.mark.parametrize("kv_bits", [16, 8, 4])
 def test_kernel_matches_gather_oracle(kv_bits):
-    """Block-boundary lengths 7/8/9/16/17 through fragmented out-of-order
-    tables + two dead rows (−1 and ≥ n_blocks sentinels): the kernel's
-    output equals the gather-view oracle to float precision, and dead rows
-    flush exact zeros on both paths."""
-    case = _pool_case(3, (7, 8, 9, 16, 17), n_blocks=24, kv_bits=kv_bits,
-                      dead_sentinels=(-1, 24))
+    """Contexts on block and chunk boundaries (P*bs − 1, P*bs, P*bs + 1), a
+    row holding the whole table and a wrapped row, through fragmented
+    out-of-order tables + two dead rows (−1 and ≥ n_blocks sentinels): the
+    kernel's output equals the gather-view oracle to float precision, and
+    dead rows flush exact zeros on both paths."""
+    case = _pool_case(3, LENGTHS, kv_bits=kv_bits, wrapped=WRAPPED,
+                      dead_sentinels=(-1, 128))
     out_k = paged_attention_pallas(*case, bits=kv_bits, interpret=True)
-    out_r = ref.paged_attention_ref(*case, bits=kv_bits)
+    out_r = _oracle(case, kv_bits)
     np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r),
                                atol=2e-5, rtol=1e-5)
     assert np.all(np.asarray(out_k)[-2:] == 0)      # dead rows: exact zeros
     assert np.all(np.asarray(out_r)[-2:] == 0)
 
 
-def test_kernel_windowed_matches_oracle():
-    """Sliding-window masking (ring semantics via token_idx) agrees."""
-    case = _pool_case(11, (9, 17, 23), n_blocks=16, kv_bits=16)
-    out_k = paged_attention_pallas(*case, bits=16, window=8, interpret=True)
-    out_r = ref.paged_attention_ref(*case, bits=16, window=8)
+@pytest.mark.parametrize("kv_bits", [16, 8, 4])
+def test_kernel_windowed_matches_oracle(kv_bits):
+    """Sliding-window masking (ring semantics via token_idx) agrees; a
+    windowed call visits every page, a wrapped row's too."""
+    case = _pool_case(11, (9, 17, 23, P * BS + 1), kv_bits=kv_bits,
+                      wrapped=WRAPPED)
+    out_k = paged_attention_pallas(*case, bits=kv_bits, window=8,
+                                   interpret=True)
+    out_r = _oracle(case, kv_bits, window=8)
     np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r),
                                atol=2e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("kv_bits,window", [(16, 0), (8, 0), (16, 24)])
+def test_kernel_multi_matches_oracle(kv_bits, window):
+    """The W = 5 speculative window: each query's causal mask and scale
+    ladder entry, with windows whose last query ends on, before and past a
+    chunk boundary, a full table and both dead-row sentinels."""
+    case = _pool_case(17, LENGTHS, kv_bits=kv_bits, w=5,
+                      dead_sentinels=(-1, 128))
+    out_k = paged_attention_pallas_multi(*case, bits=kv_bits, window=window,
+                                         interpret=True)
+    out_r = _oracle(case, kv_bits, window=window)
+    np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r),
+                               atol=2e-5, rtol=1e-5)
+    assert np.all(np.asarray(out_k)[-2:] == 0)
+
+
+@pytest.mark.parametrize("w", [1, 5])
+def test_kernel_skips_pages_past_live_bound(w):
+    """Every logical block of every row is mapped, and the pages past each
+    row's last query position hold NaN keys and values (``token_idx`` −1):
+    the kernel neither fetches nor computes them, so its output is finite
+    and equals the oracle's on the same pool with those pages zeroed."""
+    case = _pool_case(23, LENGTHS[:-1], n_blocks=160, w=w, map_all=True,
+                      dead_sentinels=(-1,))
+    q, kp, vp, ks, vs, tidx, bt, pos = case
+    past = [int(bt[r, lb]) for r, ln in enumerate(LENGTHS[:-1])
+            for lb in range(-(-ln // BS), N_LBLK)]
+    assert past
+    poisoned = (kp.at[np.asarray(past)].set(jnp.nan),
+                vp.at[np.asarray(past)].set(jnp.nan))
+    kernel = paged_attention_pallas if w == 1 else paged_attention_pallas_multi
+    out_k = np.asarray(kernel(q, *poisoned, ks, vs, tidx, bt, pos, bits=16,
+                              interpret=True))
+    zeroed = (kp.at[np.asarray(past)].set(0), vp.at[np.asarray(past)].set(0))
+    out_r = np.asarray(_oracle((q, *zeroed, ks, vs, tidx, bt, pos), 16))
+    assert np.isfinite(out_k).all()
+    np.testing.assert_allclose(out_k, out_r, atol=2e-5, rtol=1e-5)
 
 
 # ---------------------------------------------------------------------------

@@ -7,9 +7,10 @@
 * The spans change nothing the device sees: the tokens, the dispatch count
   of every executable and the trace count (none after the warm run) are
   the same with the profiler on and off.
-* ``kv_blocks_reserved`` / ``kv_blocks_written`` equal a count worked out
-  from the requests' lengths, the block size and the tokens each round
-  delivered, in greedy and in speculative segments.
+* ``kv_blocks_reserved`` / ``kv_blocks_written`` / ``kv_blocks_table``
+  equal a count worked out from the requests' lengths, the block size, the
+  block table's length and the tokens each round delivered, in greedy and
+  in speculative segments.
 * The executables and the kernel keep the names the benchmark's trace
   reduction matches (``jit_segment_fn``, ``jit_admit_paged_fn``; the
   kernel's is pinned in ``test_tpu_compile.py``).
@@ -30,7 +31,7 @@ from repro.models import transformer as T
 from repro.serving.engine import AdaptiveServer, Request, ServingConfig
 from repro.serving.scheduler import ContinuousScheduler
 
-BS, QUANTUM = 8, 4
+BS, QUANTUM, SLOTS = 8, 4, 64
 # (prompt, max_new): more requests than rows, so retired rows refill; the
 # max_new == 1 request completes at admission (a clear_rows dispatch)
 LENGTHS = [(5, 1), (12, 7), (20, 13), (9, 21), (30, 6), (17, 11)]
@@ -50,7 +51,7 @@ def parts():
 def _server(parts, mode):
     cfg, params, eng = parts
     return AdaptiveServer(cfg, params, eng, ServingConfig(
-        slots=64, max_batch=4, block_size=BS, speculate=mode == "spec",
+        slots=SLOTS, max_batch=4, block_size=BS, speculate=mode == "spec",
         draft_k=2))
 
 
@@ -65,13 +66,14 @@ def _serve(srv, reqs, warm=True):
     may trace. Beside the scheduler's own
     counters, count the KV blocks from outside: a row the round's flush
     delivered segment tokens to (any beyond its admission token) held
-    ``ceil((prompt + max_new) / BS)`` blocks in that segment and filled
-    ``ceil((prompt + delivered) / BS)`` of them. Each round's flush lands
-    at most one segment per row (greedy keeps one segment in flight,
-    speculation none)."""
+    ``ceil((prompt + max_new) / BS)`` blocks in that segment, filled
+    ``ceil((prompt + delivered) / BS)`` of them, and had a table of
+    ``slots / BS`` logical blocks. Each round's flush lands at most one
+    segment per row (greedy keeps one segment in flight, speculation
+    none)."""
     sched = ContinuousScheduler(srv, quantum=QUANTUM)
     seen = [0] * len(reqs)
-    reserved = written = 0
+    reserved = written = table = 0
     with SchedulerAudit(sched, extra_names=["_clear"]) as audit:
         rids = [sched.submit(r) for r in reqs]
         more = True
@@ -83,6 +85,7 @@ def _serve(srv, reqs, warm=True):
                     held = -(-(len(r.tokens) + r.max_new) // BS)
                     reserved += held
                     written += min(held, -(-(len(r.tokens) + got) // BS))
+                    table += -(-SLOTS // BS)
                 seen[i] = got
         if warm:
             audit.assert_no_retrace()
@@ -90,9 +93,10 @@ def _serve(srv, reqs, warm=True):
         calls = {n: audit.calls(n) for n in audit.names}
     stats = sched.paged_stats()
     return {"tokens": [sched.results[rid]["tokens"] for rid in rids],
-            "calls": calls, "counted": (reserved, written),
+            "calls": calls, "counted": (reserved, written, table),
             "counters": (stats["kv_blocks_reserved"],
-                         stats["kv_blocks_written"])}
+                         stats["kv_blocks_written"],
+                         stats["kv_blocks_table"])}
 
 
 @pytest.fixture(scope="module", params=["greedy", "spec"])
@@ -166,9 +170,9 @@ def test_spans_nest_and_leave_the_device_path_alone(runs):
 def test_kv_counters_match_an_independent_count(runs):
     _, off, on, _ = runs
     for run in (off, on):
-        reserved, written = run["counters"]
-        assert (reserved, written) == run["counted"]
-        assert 0 < written < reserved
+        reserved, written, table = run["counters"]
+        assert (reserved, written, table) == run["counted"]
+        assert 0 < written < reserved < table
 
 
 @pytest.mark.parametrize("attr,module", [("_segment", "jit_segment_fn"),
