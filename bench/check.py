@@ -2,12 +2,12 @@
 
 After the window has closed and the program's state is freed, a sample of
 the requests the window finished — drawn from the seed, always holding the
-one with most served tokens — is read by the plain reference
-(``reference.py``) once over its prompt and served tokens. The number
-compared is the widest gap by which a served token's reference logit lies
-below the reference's best at its position, over every sampled token. The
-cell's file states its limit (``check.max_gap``) and the readings it was set
-from.
+one with most served tokens — is read by the plain reference (the
+architecture module's ``make_forward``) once over its prompt and served
+tokens. The number compared is the widest gap by which a served token's
+reference logit lies below the reference's best at its position, over
+every sampled token. The cell's file states its limit (``check.max_gap``)
+and the readings it was set from.
 
 The control puts the reference computed one precision lower (activations
 on the 8-bit grid instead of the 16-bit one) in the program's place: at
@@ -39,7 +39,7 @@ def sample(finished: list, seed: int, min_tokens: int, max_requests: int):
     return out
 
 
-def gaps(params, sz: dict, conf: dict, reqs: list, pad_to: int,
+def gaps(arch, params, sz: dict, conf: dict, reqs: list, pad_to: int,
          control: bool = False) -> dict:
     """Widest gap over ``reqs`` of the tokens judged: the served ones, or
     with ``control`` the lower-precision reference's first choices in their
@@ -48,9 +48,9 @@ def gaps(params, sz: dict, conf: dict, reqs: list, pad_to: int,
         nothing = {"gap": float("inf"), "tokens": 0, "requests": 0}
         return nothing | ({"served_gap": float("inf")} if control else {})
     a_bits, w_bits = conf["a_bits"], conf["w_bits"]
-    fwd = R.make_forward(sz, a_bits=a_bits, w_bits=w_bits)
-    low = R.make_forward(sz, a_bits=conf["control_a_bits"],
-                         w_bits=w_bits) if control else None
+    fwd = arch.make_forward(sz, a_bits=a_bits, w_bits=w_bits)
+    low = arch.make_forward(sz, a_bits=conf["control_a_bits"],
+                            w_bits=w_bits) if control else None
     widest, widest_served, n_tok = 0.0, 0.0, 0
     for r in reqs:
         served = np.asarray(r.tokens, np.int64)

@@ -1,7 +1,8 @@
 """Paged-attention kernel's share of its roofline: the least time its calls
 need (per call, the larger of operations / peak and bytes / HBM bandwidth,
 from the context each live row held at that step) over the kernel's device
-time, both over the traced part of the window. Dead rows and unused blocks
+time, both over the traced part of the window, in each of the layers whose
+KV lives in the paged pool (``sz["L_attn"]``). Dead rows and unused blocks
 the kernel still visits count as time, not as need."""
 from bench import flops
 
@@ -26,5 +27,5 @@ def read(rec):
                     o, b = flops.paged_kernel_call(sz, c0 + i, rec["kv_bits"])
                     ops, byts = ops + o, byts + b
             if ops:
-                need += sz["L"] * flops.least_time_s(ops, byts, peak)
+                need += sz["L_attn"] * flops.least_time_s(ops, byts, peak)
     return 100.0 * need / k["s"] if need else None

@@ -1,23 +1,18 @@
-"""Plain reference of the served model, and the gap the check compares.
+"""What every architecture's plain reference shares, and the gap the check
+compares.
 
-A dense decoder written out in ``jax.numpy`` at float32 with
-``default_matmul_precision("highest")``: no cache, no kernels, no batching,
-one causal pass over a whole sequence. It imports nothing of the program
-and reads only the weights the benchmark made (``weights.py``).
+Each ``arch/<name>.py`` writes its model out in ``jax.numpy`` at float32
+with ``default_matmul_precision("highest")`` (``make_forward``): no cache,
+no kernels, no batching, one causal pass over a whole sequence. It imports
+nothing of the program and reads only the weights the benchmark made.
 
 It computes at the precision the configuration states: every matrix on its
 per-tensor power-of-two ``W``-bit grid, every matmul input on a per-token
 power-of-two ``A``-bit grid (signed, range ``[-2^(b-1), 2^(b-1) - 1]``,
-scale ``2^ceil(log2(amax / 2^(b-1)))``), the embedding table on the weight
-grid, the tied output head on the embedding's grid, and keys and values
-rounded to the cache's 16-bit float (kv16). Everything else is f32.
-
-Layer (pre-norm): ``x += Wo . attn(rope(Wq h), rope(Wk h), Wv h)`` with
-``h = rmsnorm(x)``; ``x += Wout (silu(g) * u)`` with ``[g | u] = Win
-rmsnorm(x)``; logits ``= E . rmsnorm(x)``. Query head ``i`` reads KV head
-``i // (H / K)``. RoPE rotates the two halves of each head (``[x1, x2] ->
-[x1 c - x2 s, x2 c + x1 s]``, frequencies ``theta^(-2j/hd)``); text
-positions make M-RoPE this same rotation.
+scale ``2^ceil(log2(amax / 2^(b-1)))``, ``po2_fake_quant``), the embedding
+table on the weight grid, the tied output head on the embedding's grid, and
+keys and values rounded to the cache's 16-bit float (kv16). Everything else
+is f32.
 """
 from __future__ import annotations
 
@@ -50,58 +45,6 @@ def _rope(x, pos, theta):
     c, s = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
     x1, x2 = x[..., :hd // 2], x[..., hd // 2:]
     return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
-
-
-def make_forward(sz: dict, *, a_bits: int, w_bits: int):
-    """``forward(params, tokens [T]) -> logits [T, V]`` (f32), jitted. The
-    layers run one at a time under ``lax.scan``, so only one layer's f32
-    weights exist at once."""
-    L, d, H, K, hd, ff = (sz[k] for k in ("L", "d", "H", "K", "hd", "ff"))
-    G = H // K
-    eps, theta = sz["eps"], sz["theta"]
-
-    def act(x):
-        return po2_fake_quant(x, a_bits, axis=-1)
-
-    def wq(w):
-        return po2_fake_quant(w.astype(jnp.float32), w_bits)
-
-    def forward(params, tokens):
-        with jax.default_matmul_precision("highest"):
-            t = tokens.shape[0]
-            pos = jnp.arange(t, dtype=jnp.int32)
-            emb = wq(params["embed"]["w"])                     # [V, d]
-            x = emb[tokens]
-            causal = pos[:, None] >= pos[None, :]
-
-            def layer(x, lp):
-                h = _rms(x, lp["norm_attn"]["g"], eps)
-                qkv = act(h) @ wq(lp["qkv"]["w"])
-                if "b" in lp["qkv"]:
-                    qkv = qkv + lp["qkv"]["b"]
-                q = qkv[:, :H * hd].reshape(t, H, hd)
-                k = qkv[:, H * hd:(H + K) * hd].reshape(t, K, hd)
-                v = qkv[:, (H + K) * hd:].reshape(t, K, hd)
-                q, k = _rope(q, pos, theta), _rope(k, pos, theta)
-                k = k.astype(jnp.bfloat16).astype(jnp.float32)   # kv16
-                v = v.astype(jnp.bfloat16).astype(jnp.float32)
-                qg = q.reshape(t, K, G, hd)
-                s = jnp.einsum("tkgd,ukd->kgtu", qg, k) / np.sqrt(hd)
-                s = jnp.where(causal[None, None], s, -jnp.inf)
-                p = jax.nn.softmax(s, axis=-1)
-                o = jnp.einsum("kgtu,ukd->tkgd", p, v).reshape(t, H * hd)
-                x = x + act(o) @ wq(lp["attn_out"]["w"])
-                h = _rms(x, lp["norm_mlp"]["g"], eps)
-                gu = act(h) @ wq(lp["mlp"]["w_in"]["w"])
-                a = jax.nn.silu(gu[:, :ff]) * gu[:, ff:]
-                x = x + act(a) @ wq(lp["mlp"]["w_out"]["w"])
-                return x, None
-
-            x, _ = jax.lax.scan(layer, x, params["layers"])
-            x = _rms(x, params["norm_f"]["g"], eps)
-            return act(x) @ emb.T
-
-    return jax.jit(forward)
 
 
 def served_gaps(logits: np.ndarray, served: np.ndarray) -> np.ndarray:

@@ -125,24 +125,23 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     from bench import check, spec
     from bench import serve as S
     from bench.load import Traffic
-    from bench.weights import sizes
 
     global _COMPILES
     if _COMPILES is None:
         _COMPILES = CompileCounter()
     t_start = time.perf_counter() if t_start is None else t_start
     w = spec.workload(name, root=root, bench=bench or spec.BENCH)
-    conf, mix, cell = w["config"], w["traffic"], w["cell"]
+    conf, mix, cell, arch = w["config"], w["traffic"], w["cell"], w["arch"]
     devs = (devices(w["workload"]["chips"]) if require_chip
             else jax.devices())
     dev = devs[0]
     # without a chip (tests), the v5e's peaks stand in
     peak = spec.peaks(dev.device_kind if require_chip else "TPU v5 lite",
                       bench or spec.BENCH)
-    cfg = S.model_config(conf)
-    sz = sizes(conf["model"])
+    cfg = S.model_config(conf, arch)
+    sz = arch.sizes(conf["model"])
     traffic = Traffic(mix, seed, cfg.vocab)
-    srv, params = S.build(cfg, conf, cell, seed)
+    srv, params = S.build(cfg, conf, cell, seed, arch)
     if traffic.longest() >= srv.slots_p:
         raise ValueError(f"{w['traffic']['kind']} needs {traffic.longest()} "
                          f"slots, the cell gives {srv.slots_p}")
@@ -227,7 +226,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
                   "admitted": win.admitted, "window_s": win.t_end,
                   "traced_steps": (tstate["k0"], tstate["k1"]),
                   "trace": red, "quantum": sched.quantum,
-                  "max_batch": sched.n_slots, "sz": sz,
+                  "max_batch": sched.n_slots, "sz": sz, "arch": arch,
                   "kv_bits": conf["kv_bits"], "peak": peak}
         metrics = {}
         for m in w["per_layer"]:
@@ -253,7 +252,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     del win, sched, srv
     gc.collect()
     t_ref = time.perf_counter()
-    got = check.gaps(params, sz, conf, picked, chk["pad_to"],
+    got = check.gaps(arch, params, sz, conf, picked, chk["pad_to"],
                      control=control)
     log(f"bench: reference over {got['requests']} requests, "
         f"{got['tokens']} served tokens, {time.perf_counter() - t_ref:.1f}s")

@@ -32,26 +32,20 @@ from .load import Traffic
 SPAN = jax.profiler.TraceAnnotation
 
 
-def model_config(conf: dict):
-    """The registry's ModelConfig with the file's overrides, checked
-    against the file's sizes (the file says how the model is run)."""
+def model_config(conf: dict, arch):
+    """The registry's ModelConfig with the file's overrides, checked by the
+    configuration's architecture module against the file's sizes (the file
+    says how the model is run)."""
     cfg = dataclasses.replace(get_config(conf["registry"]),
                               **conf.get("overrides", {}))
-    m = conf["model"]
-    s = W.sizes(m)
-    got = {"L": cfg.n_layers, "d": cfg.d_model, "H": cfg.n_heads,
-           "K": cfg.n_kv, "hd": cfg.hd, "ff": cfg.d_ff, "V": cfg.vocab,
-           "qkv_bias": cfg.qkv_bias, "theta": float(cfg.rope_theta)}
-    want = {k: s[k] for k in got}
-    if got != want or not cfg.tie_embeddings or cfg.act != "silu":
-        raise ValueError(f"registry {conf['registry']} runs {got}, the "
-                         f"configuration file states {want}")
+    arch.check_config(cfg, conf["model"])
     return cfg
 
 
-def build(cfg, conf: dict, cell: dict, seed: int):
-    """(server, params): weights from the seed, the pinned profile first."""
-    params = W.make(conf["model"], seed, compute_dtype())
+def build(cfg, conf: dict, cell: dict, seed: int, arch):
+    """(server, params): the architecture's weights from the seed, the
+    pinned profile first."""
+    params = arch.make_weights(conf["model"], seed, compute_dtype())
     expected = jax.eval_shape(
         lambda k: jax.tree_util.tree_map_with_path(
             lambda p, a: a.astype(compute_dtype()) if p[-1].key == "w" else a,

@@ -14,7 +14,7 @@ from __future__ import annotations
 
 from collections import defaultdict
 
-from .xplane import _clip, _union
+from .xplane import _clip, _union, cover_most
 
 PREFIX = "serve."
 OUTSIDE = "outside serve"
@@ -70,10 +70,6 @@ def idle_by_span(pd, window_span: str = "bench.trace") -> dict:
         for s, e in zip(edges[0::2], edges[1::2]):
             if e <= s:
                 continue
-            best, key = OUTSIDE, (0, 0)
-            for name, hs, he in spans:
-                cover = min(e, he) - max(s, hs)
-                if cover > 0 and (cover, hs - he) > key:
-                    best, key = name, (cover, hs - he)
-            out[best] += (e - s) / 1e9 / len(devices)
+            out[cover_most(s, e, spans, OUTSIDE)] += (e - s) / 1e9 \
+                / len(devices)
     return dict(out)

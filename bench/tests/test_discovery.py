@@ -1,8 +1,12 @@
-"""A configuration, a traffic mix, a cell and a per-layer metric added as
-new files are found by name, and no existing file changes."""
+"""A configuration, a traffic mix, a cell, a per-layer metric and an
+architecture added as new files are found by name, and no existing file
+changes."""
+import dataclasses
 import hashlib
 import json
 import shutil
+
+import pytest
 
 from bench import spec
 
@@ -22,7 +26,11 @@ def test_new_files_are_found_by_name(tmp_path):
 
     conf = json.loads((bench / "configs" / "granite-3-2b.json").read_text())
     conf["registry"] = "granite-3-2b"
+    conf["arch"] = "toy_arch"
     (bench / "configs" / "new-model.json").write_text(json.dumps(conf))
+    (bench / "arch" / "toy_arch.py").write_text(
+        'def sizes(model):\n    return {"arch": "toy_arch", "L": 1, '
+        '"L_attn": 0}\n')
     mix = {"kind": "offline_batch", "queue_rows": 3, "cycle": 8,
            "prompt": {"dist": "uniform", "lo": 10, "hi": 20},
            "output": {"dist": "uniform", "lo": 5, "hi": 9}}
@@ -49,6 +57,8 @@ def test_new_files_are_found_by_name(tmp_path):
     assert w["traffic"]["queue_rows"] == 3
     assert w["cell"]["check"]["max_gap"] == 0.5
     assert w["config"]["registry"] == "granite-3-2b"
+    assert w["arch"].sizes({}) == {"arch": "toy_arch", "L": 1, "L_attn": 0}
+    assert w["arch"] is spec.arch("toy_arch", bench)
     assert [m["name"] for m in w["per_layer"]] == ["new_metric.mix"]
     assert {m["name"] for m in w["end_to_end"]} == {"output_tok_s",
                                                      "setup_s"}
@@ -57,6 +67,34 @@ def test_new_files_are_found_by_name(tmp_path):
     assert reader.read({"x": 4}) == 8
     after = _digest(bench)
     assert {k: v for k, v in after.items() if k in before} == before
+
+
+def test_unknown_architecture_names_the_known_ones(tmp_path):
+    with pytest.raises(KeyError, match=r"'no_such_arch'.*dense_decoder"):
+        spec.arch("no_such_arch")
+    bench = tmp_path / "bench"
+    (bench / "arch").mkdir(parents=True)
+    for name in ("dense_decoder", "other"):
+        (bench / "arch" / f"{name}.py").write_text("")
+    with pytest.raises(KeyError, match=r"\['dense_decoder', 'other'\]"):
+        spec.arch(None, bench)
+
+
+def test_qwen2_vl_text_backbone_is_what_the_registry_runs():
+    from repro.configs import get_config
+    conf = json.loads((spec.BENCH / "configs" / "qwen2-vl-2b-text.json")
+                      .read_text())
+    arch = spec.arch(conf["arch"])
+    cfg = dataclasses.replace(get_config(conf["registry"]),
+                              **conf["overrides"])
+    arch.check_config(cfg, conf["model"])
+    assert arch.sizes(conf["model"])["L_attn"] == 28
+    # the registry's own model carries the vision frontend: not this file's
+    with pytest.raises(ValueError, match="frontend"):
+        arch.check_config(get_config(conf["registry"]), conf["model"])
+    # nor does a registry model of other sizes pass
+    with pytest.raises(ValueError):
+        arch.check_config(get_config("granite-3-2b"), conf["model"])
 
 
 def test_every_declared_metric_has_a_reader():

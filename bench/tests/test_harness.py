@@ -1,9 +1,10 @@
 """A whole run of a tiny cell on the CPU, past the look for a chip: it
-comes out correct; it comes out not correct when the timed path is broken
-underneath (a served token altered where the decode segment produces it;
-a decode segment that hands back the KV pool it was given, its writes
-lost), and when the control (the reference one activation precision lower)
-stands in for the program."""
+comes out correct, also with QKV bias, M-RoPE and heads of 128; it comes
+out not correct when the timed path is broken underneath (a served token
+altered where the decode segment produces it; a decode segment that hands
+back the KV pool it was given, its writes lost), and when the control
+(the reference one activation precision lower) stands in for the
+program."""
 import json
 
 import jax
@@ -37,6 +38,25 @@ def test_sound_run_is_correct(tree):
     assert res["metrics"]["output_tok_s"]["value"] > 0
     assert list(res)[-1] == "compared"
     json.dumps(res)
+
+
+@pytest.fixture(scope="module")
+def qwen_tree(tmp_path_factory):
+    root = tmp_path_factory.mktemp("bench-qwen")
+    return root, tiny.make_tree(root, max_gap=0.005,
+                                base="qwen2-vl-2b-text")
+
+
+def test_sound_run_with_qkv_bias_and_mrope_is_correct(qwen_tree):
+    res = _run(qwen_tree)
+    assert res["correct"], res["compared"]
+    assert res["failed"] == 0 and res["attempted"] > 0
+
+
+def test_control_with_qkv_bias_and_mrope_comes_out_not_correct(qwen_tree):
+    res = _run(qwen_tree, seed=SEED + 1, control=True)
+    assert res["program"]["correct"], res["program"]
+    assert not res["correct"], res["compared"]
 
 
 def _wrap(srv, broken):

@@ -74,6 +74,14 @@ def test_idle_goes_to_the_innermost_span_covering_most(pd):
                                               - red["busy_s"])
 
 
+def test_breakdown_names_each_gap_by_the_innermost_span_covering_most(pd):
+    gaps = xplane.reduce(pd)["idle_gaps"]
+    assert [n for n, _ in gaps] == ["serve.flush.wait", "no host span",
+                                    "serve.step", "serve.step"]
+    assert [t for _, t in gaps] == pytest.approx([30e-6, 30e-6, 20e-6,
+                                                  5e-6])
+
+
 def test_recorded_trace_has_no_program_spans_and_all_idle_outside():
     from jax.profiler import ProfileData
     path = Path(__file__).parent / "data" / "tiny.xplane.pb.gz"

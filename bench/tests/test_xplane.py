@@ -64,7 +64,7 @@ def test_breakdown_lists_are_bounded_and_attributed(red):
     times = [t for _, t in red["device_ops"]]
     assert times == sorted(times, reverse=True)
     assert len(red["idle_gaps"]) <= 10
-    assert all(n.startswith("bench.") or n == "no host span"
+    assert all(n.startswith(("bench.", "serve.")) or n == "no host span"
                for n, _ in red["idle_gaps"])
 
 

@@ -1,8 +1,9 @@
 """A benchmark tree with one tiny cell, for running the harness on a CPU.
 
-The cell runs the registry's granite-3-2b with its sizes overridden to a
-2-layer, 64-wide model, so a whole run (set-up, window, reference check)
-takes seconds.
+The cell runs a registry model (granite-3-2b by default; Qwen2-VL-2B's text
+backbone for QKV bias, M-RoPE and heads of 128) with its sizes overridden
+to a 2-layer, 64-wide model, so a whole run (set-up, window, reference
+check) takes seconds.
 """
 from __future__ import annotations
 
@@ -22,17 +23,26 @@ TINY_MODEL = {
 TINY_OVERRIDES = {"n_layers": 2, "d_model": 64, "n_heads": 4, "n_kv": 2,
                   "d_ff": 128, "vocab": 512, "head_dim": 16, "remat": False,
                   "attn_block_k": 32}
+# QKV bias, M-RoPE at text positions (sections of Qwen2-VL, so heads of 128)
+TINY_QWEN_MODEL = TINY_MODEL | {
+    "head_dim": 128, "rope_theta": 1000000.0, "attention_bias": True,
+    "rope_scaling": {"type": "mrope", "mrope_section": [16, 24, 24]}}
+TINY_QWEN_OVERRIDES = TINY_OVERRIDES | {"head_dim": 128, "frontend": None,
+                                        "n_patches": 0}
+TINY = {"granite-3-2b": (TINY_MODEL, TINY_OVERRIDES),
+        "qwen2-vl-2b-text": (TINY_QWEN_MODEL, TINY_QWEN_OVERRIDES)}
 
 
-def make_tree(root: Path, *, max_gap: float = 1.0) -> str:
+def make_tree(root: Path, *, max_gap: float = 1.0,
+              base: str = "granite-3-2b") -> str:
     """Copy ``bench/`` under ``root`` with a BENCHMARK.json holding one tiny
-    cell; returns the cell's name."""
+    cell of configuration ``base``; returns the cell's name."""
     bench = root / "bench"
     shutil.copytree(BENCH, bench, ignore=shutil.ignore_patterns(
         "__pycache__", "tests"))
-    conf = json.loads((BENCH / "configs" / "granite-3-2b.json").read_text())
-    conf.update(model=TINY_MODEL, overrides=TINY_OVERRIDES, reduced=[],
-                published={})
+    conf = json.loads((BENCH / "configs" / f"{base}.json").read_text())
+    model, overrides = TINY[base]
+    conf.update(model=model, overrides=overrides, reduced=[], published={})
     (bench / "configs" / "tiny.json").write_text(json.dumps(conf))
     mix = {"kind": "offline_batch",
            "prompt": {"dist": "uniform", "lo": 8, "hi": 24},

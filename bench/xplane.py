@@ -9,7 +9,11 @@ All events carry absolute start times on one clock.
 
 Busy time is the union of the device's operation intervals inside the
 traced window; the window is the host span ``bench.trace`` that brackets
-the traced steps.
+the traced steps. Each long idle gap is named by the host span that covers
+most of it, the innermost of those that cover equally much: one of the
+benchmark's (``bench.*``) or of the serving program's (``serve.*``, see
+``spans.py``), so a gap inside the program's ``step()`` says which part of
+it the host was in.
 """
 from __future__ import annotations
 
@@ -18,6 +22,7 @@ from collections import defaultdict
 
 _FINGERPRINT = re.compile(r"\(\d+\)$")
 _CONTROL = ("while", "conditional", "call")
+_HOST_SPANS = ("bench.", "serve.")
 
 
 def _op_name(name: str) -> str:
@@ -41,6 +46,18 @@ def _clip(intervals, lo, hi):
             if e > lo and s < hi]
 
 
+def cover_most(s: int, e: int, spans, default: str) -> str:
+    """The name of the span in ``spans`` ``[(name, start, end)]`` that
+    overlaps ``[s, e)`` most; of spans that overlap it equally, the
+    innermost (shortest). ``default`` where none overlaps it."""
+    best, key = default, (0, 0)
+    for name, hs, he in spans:
+        c = min(e, he) - max(s, hs)
+        if c > 0 and (c, hs - he) > key:
+            best, key = name, (c, hs - he)
+    return best
+
+
 def load(path: str):
     from jax.profiler import ProfileData
     return ProfileData.from_file(path)
@@ -59,7 +76,7 @@ def reduce(pd, window_span: str = "bench.trace",
         elif plane.name == "/host:CPU":
             for line in plane.lines:
                 for e in line.events:
-                    if e.name.startswith("bench."):
+                    if e.name.startswith(_HOST_SPANS):
                         host.append((e.name, e.start_ns, e.start_ns
                                      + e.duration_ns))
     win = [h for h in host if h[0] == window_span]
@@ -97,16 +114,13 @@ def reduce(pd, window_span: str = "bench.trace",
         for s, e in zip(edges[0::2], edges[1::2]):
             if e > s:
                 gaps.append((s, e))
-    steps = [h for h in host if h[0] != window_span and lo <= h[1] < hi]
+    steps = [h for h in host if h[0] not in (window_span, "bench.window")
+             and h[2] > lo and h[1] < hi]
     named = []
     for s, e in sorted(gaps, key=lambda g: g[0] - g[1])[:10]:
         # the host span that overlaps the gap most says what the host did
-        best, cover = "no host span", 0
-        for name, hs, he in steps:
-            c = min(e, he) - max(s, hs)
-            if c > cover and name != "bench.window":
-                best, cover = name, c
-        named.append([best, (e - s) / 1e9])
+        named.append([cover_most(s, e, steps, "no host span"),
+                      (e - s) / 1e9])
     top = sorted(ops.items(), key=lambda kv: -kv[1])[:10]
     return {"window_s": (hi - lo) / 1e9,
             "busy_s": sum(busy_s) / len(busy_s),
