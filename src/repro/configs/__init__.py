@@ -1,4 +1,4 @@
-"""Architecture registry: the 10 assigned archs + the paper's tiny CNN.
+"""Architecture registry: the assigned archs + the paper's tiny CNN.
 
 ``get_config(name)`` / ``get_smoke(name)`` resolve by arch id (``--arch``);
 ``ARCHS`` lists all ids; ``SHAPES`` / ``shape_applicable`` come from common.
@@ -15,6 +15,7 @@ _MODULES = {
     "qwen2-72b": "qwen2_72b",
     "glm4-9b": "glm4_9b",
     "granite-3-2b": "granite_3_2b",
+    "granite-4.0-h-micro": "granite_4_0_h_micro",
     "qwen1.5-110b": "qwen1_5_110b",
     "qwen2-vl-2b": "qwen2_vl_2b",
     "mamba2-130m": "mamba2_130m",

@@ -56,7 +56,10 @@ def qlinear(params: dict, x: jax.Array, bits_aw: jax.Array, *,
     mode (integer carriers), switched on the parameter layout.
 
     Fake mode keys: ``w`` [in,out] (+ ``b``). Native keys: ``wq`` (QTensor
-    leaves as ``wq_data``/``wq_scale`` + static bits in ``wq_bits``) (+ ``b``).
+    leaves as ``wq_data``/``wq_scale`` + static bits in ``wq_bits``) (+ ``b``),
+    or ``wqT``: the same carrier stored transposed, [out,in] (a decode image
+    whose output width is no multiple of 128, which the TPU would otherwise
+    relayout inside nested layer loops).
 
     Activations quantize **per token** (trailing-axis amax): each row's grid
     depends only on that row, so decode numerics are invariant to batch
@@ -76,6 +79,8 @@ def qlinear(params: dict, x: jax.Array, bits_aw: jax.Array, *,
         # Integer carrier: a native deployment layout, or a decode-scan image
         # grafted next to the float master (it wins over ``w``).
         w = dequantize(params["wq"], compute_dtype)
+    elif "wqT" in params:
+        w = dequantize(params["wqT"], compute_dtype).T
     else:
         w = fake_quant_dynamic(params["w"], bits_aw[1],
                                SIGNED_SYM).astype(compute_dtype)

@@ -22,7 +22,7 @@ from .layers import init_linear, qlinear, rms_norm
 from .pshard import constrain
 
 __all__ = ["SSMConfig", "init_ssm", "ssd_forward", "ssm_decode_step", "SSMState",
-           "init_ssm_state", "ssm_prefill_state"]
+           "init_ssm_state", "ssm_prefill_state", "ssm_recur"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -223,9 +223,33 @@ def ssm_prefill_state(final_state, batch, d_model, cfg: SSMConfig) -> SSMState:
     return SSMState(h=h_final, conv=conv_tail.astype(jnp.float32))
 
 
+def ssm_recur(h: jax.Array, x: jax.Array, dt: jax.Array, a: jax.Array,
+              b: jax.Array, c: jax.Array, d: jax.Array):
+    """One token's state update, every row: ``h <- h*exp(dt*A) + (dt*x) (x) B``,
+    ``y = h.C + D*x``. h ``[B, H, P, N]``, x ``[B, H, P]``, dt ``[B, H]``
+    (after softplus), a/d ``[H]``, b/c ``[B, G, N]`` → (y ``[B, H, P]``,
+    h). The XLA form: the CPU path and the oracle of the Pallas kernel
+    (:mod:`repro.kernels.ssm_decode`)."""
+    rep = h.shape[1] // b.shape[1]
+    bh = jnp.repeat(b, rep, axis=1).astype(jnp.float32)
+    ch = jnp.repeat(c, rep, axis=1).astype(jnp.float32)
+    dec = jnp.exp(dt * a)                                 # [B, H]
+    h_new = h * dec[..., None, None] + \
+        jnp.einsum("bhp,bhn->bhpn", x * dt[..., None], bh)
+    y = jnp.einsum("bhpn,bhn->bhp", h_new, ch) + x * d[None, :, None]
+    return y, h_new
+
+
 def ssm_decode_step(params: dict, x: jax.Array, state: SSMState,
-                    bits_in: jax.Array, bits_out: jax.Array, cfg: SSMConfig):
-    """One-token recurrent step. x ``[B, 1, d_model]`` → (y, new_state)."""
+                    bits_in: jax.Array, bits_out: jax.Array, cfg: SSMConfig,
+                    recur=None):
+    """One-token recurrent step. x ``[B, 1, d_model]`` → (y, new_state).
+
+    ``recur`` (default :func:`ssm_recur`) computes the state update from
+    ``state.h`` as given, with :func:`ssm_recur`'s other arguments; it
+    returns ``(y, h)`` and its ``h`` becomes the new state's. A caller may
+    pass a whole stack of layers' states in ``state.h`` with a ``recur``
+    that updates one layer of it in place."""
     bsz, _, d_model = x.shape
     di = cfg.d_inner(d_model)
     h_heads = cfg.n_heads(d_model)
@@ -242,16 +266,12 @@ def ssm_decode_step(params: dict, x: jax.Array, state: SSMState,
 
     xs, b_, c_ = jnp.split(conv_out, [di, di + g * n], axis=-1)
     xh = xs.reshape(bsz, h_heads, p_dim).astype(jnp.float32)
-    rep = h_heads // g
-    bh = jnp.repeat(b_.reshape(bsz, g, n), rep, axis=1).astype(jnp.float32)
-    ch = jnp.repeat(c_.reshape(bsz, g, n), rep, axis=1).astype(jnp.float32)
-
     a = -jnp.exp(params["A_log"].astype(jnp.float32))
     dt = jax.nn.softplus(dt.astype(jnp.float32) + params["dt_bias"])  # [B, H]
-    dec = jnp.exp(dt * a)                                 # [B, H]
-    h_new = state.h * dec[..., None, None] + \
-        jnp.einsum("bhp,bhn->bhpn", xh * dt[..., None], bh)
-    y = jnp.einsum("bhpn,bhn->bhp", h_new, ch) + xh * params["D"][None, :, None]
+    y, h_new = (recur or ssm_recur)(
+        state.h, xh, dt, a, b_.reshape(bsz, g, n).astype(jnp.float32),
+        c_.reshape(bsz, g, n).astype(jnp.float32),
+        params["D"].astype(jnp.float32))
     y = y.reshape(bsz, di) * jax.nn.silu(z.astype(jnp.float32))
     y = rms_norm({"g": params["norm_g"]}, y)
     out = qlinear(params["out_proj"], y[:, None, :].astype(x.dtype), bits_out)

@@ -1,4 +1,6 @@
-"""LM-family model assembly: dense / MoE / SSM / hybrid / VLM / audio.
+"""LM-family model assembly: dense / MoE / SSM / hybrid / VLM / audio, and
+interleaved stacks whose layers are Mamba-2 or attention by a per-layer
+pattern.
 
 One configurable stack covers all ten assigned architectures. Invariants:
 
@@ -21,6 +23,7 @@ Public entry points:
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import math
 from functools import partial
 from typing import Any, Optional
@@ -63,6 +66,7 @@ __all__ = ["ModelConfig", "init_params", "quant_layer_names", "forward",
 class ModelConfig:
     name: str
     family: str                      # dense | moe | ssm | hybrid | vlm | audio
+    #                                  | interleaved (``layer_types``)
     n_layers: int
     d_model: int
     n_heads: int
@@ -71,6 +75,7 @@ class ModelConfig:
     vocab: int
     head_dim: Optional[int] = None
     rope_theta: float = 1e6
+    rope: bool = True                # False: no positional encoding (NoPE)
     qkv_bias: bool = False
     mrope: bool = False
     mrope_sections: tuple[int, ...] = (16, 24, 24)
@@ -82,6 +87,9 @@ class ModelConfig:
     ssm: Optional[SSMConfig] = None
     frontend: Optional[str] = None   # audio | vision (stub, DESIGN §4)
     n_patches: int = 0               # VLM vision-prefix length
+    # interleaved family: each layer's mixer, "mamba" or "attention"; every
+    # layer is pre-norm mixer + pre-norm gated MLP, both residual
+    layer_types: Optional[tuple[str, ...]] = None
     feature_dim: int = 512           # audio stub frame-embedding dim
     tie_embeddings: bool = False
     remat: bool = True
@@ -100,15 +108,17 @@ class ModelConfig:
 
     @property
     def has_attn(self) -> bool:
-        return self.family in ("dense", "moe", "hybrid", "vlm", "audio")
+        return self.family in ("dense", "moe", "hybrid", "vlm", "audio",
+                               "interleaved")
 
     @property
     def has_ssm(self) -> bool:
-        return self.family in ("ssm", "hybrid")
+        return self.family in ("ssm", "hybrid", "interleaved")
 
     @property
     def has_mlp(self) -> bool:
-        return self.family in ("dense", "hybrid", "vlm", "audio")
+        return self.family in ("dense", "hybrid", "vlm", "audio",
+                               "interleaved")
 
     def window(self, skv: int) -> int:
         return self.sliding_window if self.sliding_window else skv + 1
@@ -124,17 +134,35 @@ _SITES = {
     "ssm": ("ssm_in", "ssm_out"),
     "hybrid": ("qkv", "attn_out", "ssm_in", "ssm_out", "mlp_in", "mlp_out"),
 }
+# an interleaved stack's layer takes its kind's sites; both kinds have four,
+# at the same places, so the bits table stays [L, 4] and a site's index is
+# the same in either kind
+_KIND_SITES = {"attention": ("qkv", "attn_out", "mlp_in", "mlp_out"),
+               "mamba": ("ssm_in", "ssm_out", "mlp_in", "mlp_out")}
 _GLOBAL_SITES = ("embed", "lm_head")
 
 
-def sites(cfg: ModelConfig) -> tuple[str, ...]:
+def sites(cfg: ModelConfig, layer: int = 0) -> tuple[str, ...]:
+    """Quantization sites of ``layer`` (every layer's, in a uniform stack)."""
+    if cfg.layer_types:
+        return _KIND_SITES[cfg.layer_types[layer]]
     return _SITES[cfg.family]
+
+
+def layers_of(cfg: ModelConfig, kind: str) -> tuple[int, ...]:
+    """Depth indices of the layers holding ``kind``'s state: "attention"
+    (KV) or "mamba" (recurrent state). In a uniform stack that is every
+    layer or none; Hymba's layers hold both."""
+    if cfg.layer_types:
+        return tuple(i for i, k in enumerate(cfg.layer_types) if k == kind)
+    has = cfg.has_attn if kind == "attention" else cfg.has_ssm
+    return tuple(range(cfg.n_layers)) if has else ()
 
 
 def quant_layer_names(cfg: ModelConfig) -> tuple[str, ...]:
     """Names for Profile construction: globals + per-depth per-site."""
     return _GLOBAL_SITES + tuple(
-        f"L{i}.{s}" for i in range(cfg.n_layers) for s in sites(cfg))
+        f"L{i}.{s}" for i in range(cfg.n_layers) for s in sites(cfg, i))
 
 
 def split_bits(cfg: ModelConfig, bits_row: jax.Array):
@@ -145,6 +173,8 @@ def split_bits(cfg: ModelConfig, bits_row: jax.Array):
 
 
 def _site_idx(cfg: ModelConfig, name: str) -> int:
+    if cfg.layer_types:
+        return next(s.index(name) for s in _KIND_SITES.values() if name in s)
     return sites(cfg).index(name)
 
 
@@ -178,10 +208,37 @@ def _init_layer(cfg: ModelConfig, key: jax.Array) -> dict:
     return p
 
 
+def _init_kind_layer(cfg: ModelConfig, kind: str, key: jax.Array) -> dict:
+    """One layer of an interleaved stack: its mixer (GQA attention or
+    Mamba-2) and the gated MLP, each behind its own RMS norm."""
+    ks = jax.random.split(key, 3)
+    d, hd = cfg.d_model, cfg.hd
+    p: dict[str, Any] = {"norm_mlp": init_norm(d),
+                         "mlp": init_mlp(ks[0], d, cfg.d_ff)}
+    if kind == "attention":
+        p["qkv"] = init_linear(ks[1], d, (cfg.n_heads + 2 * cfg.n_kv) * hd,
+                               bias=cfg.qkv_bias)
+        p["attn_out"] = init_linear(ks[2], cfg.n_heads * hd, d)
+        p["norm_attn"] = init_norm(d)
+    else:
+        p["ssm"] = init_ssm(ks[1], d, cfg.ssm)
+        p["norm_ssm"] = init_norm(d)
+    return p
+
+
 def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
     k_emb, k_layers, k_head = jax.random.split(key, 3)
-    layer_keys = jax.random.split(k_layers, cfg.n_layers)
-    layers = jax.vmap(lambda k: _init_layer(cfg, k))(layer_keys)
+    if cfg.layer_types:
+        # one stack per kind, in depth order within each: no layer holds
+        # weights of the other kind
+        layers = {}
+        for kind, kk in zip(_KIND_SITES, jax.random.split(k_layers)):
+            keys = jax.random.split(kk, len(layers_of(cfg, kind)))
+            layers[kind] = jax.vmap(
+                partial(_init_kind_layer, cfg, kind))(keys)
+    else:
+        layer_keys = jax.random.split(k_layers, cfg.n_layers)
+        layers = jax.vmap(lambda k: _init_layer(cfg, k))(layer_keys)
     p = {
         "layers": layers,
         "norm_f": init_norm(cfg.d_model, bias=cfg.norm == "ln"),
@@ -232,7 +289,7 @@ def _attn_qkv(cfg: ModelConfig, lp: dict, x: jax.Array, lb: jax.Array,
         pos3 = text_mrope_positions(positions)
         q = apply_mrope(q, pos3, cfg.mrope_sections, cfg.rope_theta)
         k = apply_mrope(k, pos3, cfg.mrope_sections, cfg.rope_theta)
-    else:
+    elif cfg.rope:
         q = apply_rope(q, positions, cfg.rope_theta)
         k = apply_rope(k, positions, cfg.rope_theta)
     # NB: no head-axis constraint here — attention internals are S-sharded
@@ -377,7 +434,9 @@ def _embed_inputs(cfg: ModelConfig, params: dict, bits_row: jax.Array,
 
 
 def forward(params: dict, cfg: ModelConfig, bits_row: jax.Array, batch: dict,
-            collect: bool = False, kv_sched: Optional[jax.Array] = None):
+            collect: bool = False, kv_sched: Optional[jax.Array] = None,
+            state_into: Optional[tuple] = None,
+            layer_images: Optional[tuple] = None):
     """Backbone over a full sequence.
 
     Returns (hidden [B,S,d], aux_loss, collected) where ``collected`` stacks
@@ -386,7 +445,12 @@ def forward(params: dict, cfg: ModelConfig, bits_row: jax.Array, batch: dict,
     precision-policy row — each layer's fresh K/V are refined at its entry's
     bit-width before attention/collection; ``None`` keeps the lowering
     byte-identical to the policy-free path (the scan xs tuple is unchanged).
+    ``state_into``, ``layer_images`` (interleaved stacks): see
+    :func:`_forward_interleaved`.
     """
+    if cfg.layer_types:
+        return _forward_interleaved(params, cfg, bits_row, batch, collect,
+                                    kv_sched, state_into, layer_images)
     x, positions, valid = _embed_inputs(cfg, params, bits_row, batch)
     _, _, layer_bits = split_bits(cfg, bits_row)
 
@@ -449,7 +513,9 @@ def _lm_head_params(cfg: ModelConfig, params: dict) -> dict:
 def _logits(cfg: ModelConfig, params: dict, bits_row: jax.Array,
             h: jax.Array) -> jax.Array:
     _, hb, _ = split_bits(cfg, bits_row)
-    return qlinear(_lm_head_params(cfg, params), h, hb).astype(jnp.float32)
+    head = (_image_head(cfg, params) if cfg.layer_types
+            else _lm_head_params(cfg, params))
+    return qlinear(head, h, hb).astype(jnp.float32)
 
 
 def chunked_xent(cfg: ModelConfig, params: dict, bits_row: jax.Array,
@@ -507,8 +573,9 @@ def _stack_layerwise(fn, n_layers: int):
 
 def init_caches(cfg: ModelConfig, batch: int, slots: int, *,
                 kv_bits: int = 16) -> dict:
-    """Decode caches, stacked [L, ...]. ``slots`` bounds the attention window
-    (SWA archs allocate only their window — what makes hymba long_500k O(W))."""
+    """Decode caches, stacked [L, ...] over the layers that hold each kind
+    (:func:`layers_of`). ``slots`` bounds the attention window (SWA archs
+    allocate only their window — what makes hymba long_500k O(W))."""
     caches: dict[str, Any] = {}
     if cfg.has_attn:
         eff = min(slots, cfg.sliding_window) if cfg.sliding_window else slots
@@ -516,10 +583,11 @@ def init_caches(cfg: ModelConfig, batch: int, slots: int, *,
         caches["kv"] = _stack_layerwise(
             lambda: init_kv_cache(batch, eff, cfg.n_kv, cfg.hd, bits=kv_bits,
                                   dtype=dt),
-            cfg.n_layers)
+            len(layers_of(cfg, "attention")))
     if cfg.has_ssm:
         caches["ssm"] = _stack_layerwise(
-            lambda: init_ssm_state(batch, cfg.d_model, cfg.ssm), cfg.n_layers)
+            lambda: init_ssm_state(batch, cfg.d_model, cfg.ssm),
+            len(layers_of(cfg, "mamba")))
     return caches
 
 
@@ -565,10 +633,11 @@ def init_paged_caches(cfg: ModelConfig, batch: int, slots: int, *,
         caches["kv"] = _stack_layerwise(
             lambda: init_paged_kv_cache(batch, nb, bs, n_lblk, cfg.n_kv,
                                         cfg.hd, bits=kv_bits, dtype=dt),
-            cfg.n_layers)
+            len(layers_of(cfg, "attention")))
     if cfg.has_ssm:
         caches["ssm"] = _stack_layerwise(
-            lambda: init_ssm_state(batch, cfg.d_model, cfg.ssm), cfg.n_layers)
+            lambda: init_ssm_state(batch, cfg.d_model, cfg.ssm),
+            len(layers_of(cfg, "mamba")))
     return caches
 
 
@@ -694,6 +763,57 @@ def amax_for_scale(scale: np.ndarray, qmax: float,
     return out
 
 
+def _attn_decode(cfg: ModelConfig, lp: dict, lb: jax.Array, xin: jax.Array,
+                 positions: jax.Array, pos: jax.Array, cache: dict,
+                 paged_backend: str, ke: Optional[jax.Array]):
+    """The attention half of one layer's decode step: QKV of the normed
+    ``xin [B, 1, d]`` at ``positions [B, 1]``, the cache write, attention
+    and the output projection. ``cache`` holds the layer's ``"kv"`` (and
+    the segment's ``"kv_view"``); returns ``(attn [B, 1, d], the updated
+    entries)``."""
+    b = xin.shape[0]
+    q, k, v = _attn_qkv(cfg, lp, xin, lb, positions)
+    if ke is not None:
+        k, v = kv_refine(k, ke), kv_refine(v, ke)
+    new = {}
+    if "kv_view" in cache:
+        # paged fast path (decode_segment): the block table is
+        # fixed for the whole segment, so the dense per-row view
+        # was gathered ONCE at segment entry, rides the carry, and
+        # takes every read AND write of the segment — exactly the
+        # contiguous ring's per-step cost. The pool passes through
+        # untouched; decode_segment folds the view's blocks back
+        # through the block tables once, at segment exit.
+        kvc = cache["kv"]
+        view = update_kv_cache(cache["kv_view"], k, v, pos)
+        attn = decode_attention(
+            q, view, pos,
+            window=cfg.window(view.token_idx.shape[1]))
+        new["kv_view"] = view
+    elif isinstance(cache["kv"], PagedKVCache):
+        kvc = update_paged_kv_cache(cache["kv"], k, v, pos)
+        slots_p = kvc.block_table.shape[1] * kvc.k.shape[1]
+        if paged_backend == "pallas":
+            # in-place path: the kernel streams mapped pool blocks
+            # through the block table; no dense view is built
+            attn = paged_decode_attention(
+                q, kvc, pos, window=cfg.window(slots_p))
+        else:
+            # standalone paged step: gather the view on the spot
+            view = paged_view(kvc)
+            attn = decode_attention(
+                q, view, pos, window=cfg.window(slots_p))
+    else:
+        kvc = update_kv_cache(cache["kv"], k, v, pos)
+        attn = decode_attention(
+            q, kvc, pos,
+            window=cfg.window(kvc.token_idx.shape[1]))
+    attn = qlinear(lp["attn_out"], attn.reshape(b, 1, -1),
+                   lb[_site_idx(cfg, "attn_out")])
+    new["kv"] = kvc
+    return attn, new
+
+
 def decode_step(params: dict, cfg: ModelConfig, bits_row: jax.Array,
                 tokens: jax.Array, pos: jax.Array, caches: dict,
                 row_valid: Optional[jax.Array] = None,
@@ -716,6 +836,9 @@ def decode_step(params: dict, cfg: ModelConfig, bits_row: jax.Array,
     row — the step's fresh K/V are refined per layer before the cache write
     and the attention read, exactly like the prefill paths.
     """
+    if cfg.layer_types:
+        return _decode_interleaved(params, cfg, bits_row, tokens, pos,
+                                   caches, paged_backend, kv_sched)
     eb, _, layer_bits = split_bits(cfg, bits_row)
     x = embed_lookup(params["embed"], tokens, eb)
     positions = pos[:, None].astype(jnp.int32)
@@ -730,44 +853,9 @@ def decode_step(params: dict, cfg: ModelConfig, bits_row: jax.Array,
         new_cache = dict(cache)
         if cfg.has_attn:
             xin = _norm(cfg, lp["norm_attn"], x)
-            q, k, v = _attn_qkv(cfg, lp, xin, lb, positions)
-            if ke is not None:
-                k, v = kv_refine(k, ke), kv_refine(v, ke)
-            if "kv_view" in cache:
-                # paged fast path (decode_segment): the block table is
-                # fixed for the whole segment, so the dense per-row view
-                # was gathered ONCE at segment entry, rides the carry, and
-                # takes every read AND write of the segment — exactly the
-                # contiguous ring's per-step cost. The pool passes through
-                # untouched; decode_segment folds the view's blocks back
-                # through the block tables once, at segment exit.
-                kvc = cache["kv"]
-                view = update_kv_cache(cache["kv_view"], k, v, pos)
-                attn = decode_attention(
-                    q, view, pos,
-                    window=cfg.window(view.token_idx.shape[1]))
-                new_cache["kv_view"] = view
-            elif isinstance(cache["kv"], PagedKVCache):
-                kvc = update_paged_kv_cache(cache["kv"], k, v, pos)
-                slots_p = kvc.block_table.shape[1] * kvc.k.shape[1]
-                if paged_backend == "pallas":
-                    # in-place path: the kernel streams mapped pool blocks
-                    # through the block table; no dense view is built
-                    attn = paged_decode_attention(
-                        q, kvc, pos, window=cfg.window(slots_p))
-                else:
-                    # standalone paged step: gather the view on the spot
-                    view = paged_view(kvc)
-                    attn = decode_attention(
-                        q, view, pos, window=cfg.window(slots_p))
-            else:
-                kvc = update_kv_cache(cache["kv"], k, v, pos)
-                attn = decode_attention(
-                    q, kvc, pos,
-                    window=cfg.window(kvc.token_idx.shape[1]))
-            attn = qlinear(lp["attn_out"], attn.reshape(b, 1, -1),
-                           lb[_site_idx(cfg, "attn_out")])
-            new_cache["kv"] = kvc
+            attn, upd = _attn_decode(cfg, lp, lb, xin, positions, pos,
+                                     cache, paged_backend, ke)
+            new_cache.update(upd)
         if cfg.family == "hybrid":
             ssm_out, st = ssm_decode_step(lp["ssm"], xin, cache["ssm"],
                                           lb[_site_idx(cfg, "ssm_in")],
@@ -824,6 +912,240 @@ def decode_step(params: dict, cfg: ModelConfig, bits_row: jax.Array,
     return logits, new_caches
 
 
+# ---------------------------------------------------------------------------
+# interleaved stacks: each layer Mamba-2 + MLP or attention + MLP
+# ---------------------------------------------------------------------------
+
+def _period_plan(cfg: ModelConfig):
+    """The pattern as ``periods`` repeats of its shortest period, each kind's
+    layers per period, and the period as runs of like layers ``(kind,
+    first, n)`` (``first`` counts the period's earlier layers of the kind)."""
+    kinds = tuple(cfg.layer_types)
+    n = len(kinds)
+    p = next(p for p in range(1, n + 1)
+             if n % p == 0 and kinds == kinds[:p] * (n // p))
+    runs, per = [], dict.fromkeys(_KIND_SITES, 0)
+    for kind, grp in itertools.groupby(kinds[:p]):
+        k = len(list(grp))
+        runs.append((kind, per[kind], k))
+        per[kind] += k
+    return n // p, per, runs
+
+
+def _over_layers(cfg: ModelConfig, layer, carry):
+    """``carry = layer(kind, j, carry)`` over the stack in depth order, ``j``
+    the layer's (traced) index among its kind's. A scan over the pattern's
+    periods holds one scan per run of the period, so the program has one
+    body per run whatever the depth."""
+    n_per, per, runs = _period_plan(cfg)
+
+    def period(carry, k):
+        for kind, first, n in runs:
+            carry, _ = jax.lax.scan(
+                lambda c, j, kind=kind: (layer(kind, j, c), None), carry,
+                k * per[kind] + first + jnp.arange(n, dtype=jnp.int32))
+        return carry, None
+
+    return jax.lax.scan(period, carry, jnp.arange(n_per, dtype=jnp.int32))[0]
+
+
+def _by_kind(cfg: ModelConfig, rows: jax.Array) -> dict:
+    """Per-layer rows ``[L, ...]`` split into each kind's, in depth order."""
+    return {kind: rows[np.asarray(layers_of(cfg, kind), np.int32)]
+            for kind in _KIND_SITES}
+
+
+def _at(tree, j):
+    return jax.tree.map(lambda a: a[j], tree)
+
+
+def _mlp_half(cfg: ModelConfig, lp: dict, lb: jax.Array, x: jax.Array):
+    return x + mlp(lp["mlp"], _norm(cfg, lp["norm_mlp"], x),
+                   lb[_site_idx(cfg, "mlp_in")], lb[_site_idx(cfg, "mlp_out")],
+                   gated=cfg.act == "silu", act=cfg.act)
+
+
+def _forward_interleaved(params: dict, cfg: ModelConfig, bits_row: jax.Array,
+                         batch: dict, collect: bool,
+                         kv_sched: Optional[jax.Array],
+                         state_into: Optional[tuple] = None,
+                         layer_images: Optional[tuple] = None):
+    """:func:`forward` of an interleaved stack. ``collect`` writes each
+    attention layer's K/V and each Mamba layer's final state and conv tail
+    into buffers the layers carry, stacked by kind (``[L_attn, ...]``,
+    ``[L_ssm, ...]``). ``state_into`` ``(state, rows)``: the states go into
+    rows ``rows`` (out of range: dropped) of ``state``, a pool's stacked
+    :class:`SSMState`, layer by layer — no ``[L_ssm, B, ...]`` buffer of
+    the batch's states is made. ``layer_images``: as
+    :func:`_decode_interleaved` takes them."""
+    x, positions, valid = _embed_inputs(cfg, params, bits_row, batch)
+    _, _, layer_bits = split_bits(cfg, bits_row)
+    lbits = _by_kind(cfg, layer_bits)
+    kes = (None if kv_sched is None else
+           _by_kind(cfg, jnp.asarray(kv_sched, jnp.int32))["attention"])
+    b, s, d = x.shape
+    col = {}
+    if collect:
+        sc = cfg.ssm
+        kv = jnp.zeros((len(layers_of(cfg, "attention")), b, s, cfg.n_kv,
+                        cfg.hd), x.dtype)
+        lm = len(layers_of(cfg, "mamba"))
+        if state_into is None:
+            col = {"h": jnp.zeros((lm, b, sc.n_heads(d), sc.head_dim,
+                                   sc.d_state), jnp.float32),
+                   "conv": jnp.zeros((lm, b, sc.d_conv - 1, sc.conv_dim(d)),
+                                     jnp.float32)}
+        else:
+            col = {"h": state_into[0].h, "conv": state_into[0].conv}
+        col |= {"k": kv, "v": kv}
+    rows = slice(None) if state_into is None else state_into[1]
+
+    def layer(kind, j, carry):
+        x, col = carry
+        lp = _layer_weights(params, kind, j, layer_images)
+        lb = lbits[kind][j]
+        col = dict(col)
+        if kind == "attention":
+            xin = _norm(cfg, lp["norm_attn"], x)
+            q, k, v = _attn_qkv(cfg, lp, xin, lb, positions)
+            if kes is not None:
+                k, v = kv_refine(k, kes[j]), kv_refine(v, kes[j])
+            attn = _attend(cfg, q, k, v, s, kv_valid=valid)
+            x = x + qlinear(lp["attn_out"], attn.reshape(b, s, -1),
+                            lb[_site_idx(cfg, "attn_out")])
+            if collect:
+                col["k"], col["v"] = col["k"].at[j].set(k), \
+                    col["v"].at[j].set(v)
+        else:
+            out = ssd_forward(lp["ssm"], _norm(cfg, lp["norm_ssm"], x),
+                              lb[_site_idx(cfg, "ssm_in")],
+                              lb[_site_idx(cfg, "ssm_out")], cfg.ssm,
+                              return_final_state=collect,
+                              unroll=cfg.unroll_inner, valid=valid)
+            if collect:
+                out, (h, tail) = out
+                col["h"] = col["h"].at[j, rows].set(h, mode="drop")
+                col["conv"] = col["conv"].at[j, rows].set(
+                    tail.astype(jnp.float32), mode="drop")
+            x = x + out
+        return _mlp_half(cfg, lp, lb, x), col
+
+    fn = layer
+    if cfg.remat:
+        def fn(kind, j, carry):
+            return jax.checkpoint(partial(layer, kind),
+                                  policy=_remat_policy(cfg))(j, carry)
+    x, col = _over_layers(cfg, fn, (x, col))
+    x = _norm(cfg, params["norm_f"], x)
+    collected = (((col["k"], col["v"]), (col["h"], col["conv"]))
+                 if collect else ())
+    return x, jnp.zeros((), jnp.float32), collected
+
+
+def _image_split(params: dict, prequant: dict, pid: jax.Array):
+    """An interleaved stack's weights under profile ``pid``'s image: the
+    params with the global images (embedding, head) grafted on, and
+    ``(layer images, img)`` for the layer loop, where each layer takes its
+    own slice — a copy of the whole image per call would not fit beside
+    the recurrent state at full width."""
+    img = prequant["image_of"][pid]
+    images = dict(prequant["images"])
+    lay = images.pop("layers", {})
+    return overlay_params(params, _at(images, img)), (lay, img)
+
+
+def _layer_weights(params: dict, kind: str, j, layer_images):
+    lp = _at(params["layers"][kind], j)
+    if layer_images is not None and kind in layer_images[0]:
+        img = layer_images[1]
+        lp = overlay_params(lp, jax.tree.map(lambda a: a[img, j],
+                                             layer_images[0][kind]))
+    return lp
+
+
+def _decode_interleaved(params: dict, cfg: ModelConfig, bits_row: jax.Array,
+                        tokens: jax.Array, pos: jax.Array, caches: dict,
+                        paged_backend: str, kv_sched: Optional[jax.Array],
+                        layer_images: Optional[tuple] = None):
+    """:func:`decode_step` of an interleaved stack. The caches ride the
+    layer loop's carry whole — KV ``[L_attn, ...]``, recurrent state
+    ``[L_ssm, ...]`` — and each layer updates its own entry. With
+    ``paged_backend="pallas"`` the state update is the Pallas kernel, which
+    writes the layer's state in place inside the stacked buffer.
+    ``layer_images`` ``(images, img)``: the layers' stacked weight images
+    (``[n_images, L_kind, ...]`` per kind) and the (traced) image to use;
+    each layer grafts its own slice onto its weights."""
+    eb, _, layer_bits = split_bits(cfg, bits_row)
+    x = embed_lookup(params["embed"], tokens, eb)
+    positions = pos[:, None].astype(jnp.int32)
+    lbits = _by_kind(cfg, layer_bits)
+    kes = (None if kv_sched is None else
+           _by_kind(cfg, jnp.asarray(kv_sched, jnp.int32))["attention"])
+
+    def layer(kind, j, carry):
+        x, cch = carry
+        lp = _layer_weights(params, kind, j, layer_images)
+        lb = lbits[kind][j]
+        cch = dict(cch)
+        if kind == "attention":
+            xin = _norm(cfg, lp["norm_attn"], x)
+            attn, upd = _attn_decode(
+                cfg, lp, lb, xin, positions, pos,
+                {k: _at(cch[k], j) for k in ("kv", "kv_view") if k in cch},
+                paged_backend, None if kes is None else kes[j])
+            for k, new in upd.items():
+                cch[k] = jax.tree.map(lambda a, u: a.at[j].set(u), cch[k],
+                                      new)
+            x = x + attn
+        else:
+            st = cch["ssm"]
+            xin = _norm(cfg, lp["norm_ssm"], x)
+            bits = (lb[_site_idx(cfg, "ssm_in")], lb[_site_idx(cfg, "ssm_out")])
+            if paged_backend == "pallas":
+                y, new = ssm_decode_step(
+                    lp["ssm"], xin, SSMState(st.h, st.conv[j]), *bits,
+                    cfg.ssm, recur=partial(_ssm_kernel_recur, j))
+                h = new.h
+            else:
+                y, new = ssm_decode_step(
+                    lp["ssm"], xin, SSMState(st.h[j], st.conv[j]), *bits,
+                    cfg.ssm)
+                h = st.h.at[j].set(new.h)
+            cch["ssm"] = SSMState(h=h, conv=st.conv.at[j].set(new.conv))
+            x = x + y
+        return _mlp_half(cfg, lp, lb, x), cch
+
+    x, caches = _over_layers(cfg, layer, (x, dict(caches)))
+    x = _norm(cfg, params["norm_f"], x)
+    return _logits(cfg, params, bits_row, x)[:, 0], caches
+
+
+def _image_head(cfg: ModelConfig, params: dict) -> dict:
+    """The output head an interleaved stack multiplies by: under a weight
+    image, the image — the head's own, else (tied, same bits) the
+    embedding's transposed — so no step fake-quants the f32 master table;
+    without one, :func:`_lm_head_params`."""
+    emb = params["embed"]
+    if "lm_head" in params:
+        return params["lm_head"]
+    if not cfg.tie_embeddings:
+        return _lm_head_params(cfg, params)
+    if "wq" in emb:
+        return {"wq": emb["wq"]._replace(data=emb["wq"].data.T)}
+    if "wfq" in emb:
+        return {"wfq": emb["wfq"].T}
+    return _lm_head_params(cfg, params)
+
+
+def _ssm_kernel_recur(layer, h, x, dt, a, b, c, d):
+    """:func:`~repro.models.ssm.ssm_recur`'s contract over the stacked state
+    ``h [L_ssm, ...]``, updating layer ``layer`` in place through the Pallas
+    kernel (interpret mode off the TPU)."""
+    from repro.kernels.ssm_decode import ssm_decode_pallas
+    return ssm_decode_pallas(h, layer, x, dt, a, b, c, d,
+                             interpret=jax.default_backend() != "tpu")
+
+
 def prequant_decode_weights(params: dict, cfg: ModelConfig,
                             table: jax.Array) -> dict:
     """Hoist weight fake-quant out of the decode loop.
@@ -859,7 +1181,9 @@ def prequant_decode_weights(params: dict, cfg: ModelConfig,
     ns = len(sites(cfg))
     layer_rows = w_rows[:, 2:].reshape(len(w_rows), cfg.n_layers, ns)
 
-    def quant(w, wb, narrow):
+    def quant(w, wb, narrow, transposed=False):
+        if narrow and transposed:
+            return {"wqT": po2_carrier(w.T, wb)}   # per-tensor grid: same
         if narrow:
             return {"wq": po2_carrier(w, wb)}
         return {"wfq": fake_quant_dynamic(w, wb, SIGNED_SYM)}
@@ -870,38 +1194,59 @@ def prequant_decode_weights(params: dict, cfg: ModelConfig,
         def glob(w, i):
             return quant(w, w_row[i], bool((w_rows[:, i] <= 8).all()))
 
-        def stacked(w, name):             # w [L, ...] with per-layer bits
-            j = _site_idx(cfg, name)
-            narrow = bool((layer_rows[:, :, j] <= 8).all())
-            return jax.lax.map(lambda a: quant(a[0], a[1], narrow),
-                               (w, layer_bits[:, j]))
+        def layer_images(lp, idx):        # a stack of the layers ``idx``
+            def stacked(w, name):         # w [len(idx), ...], per-layer bits
+                j = _site_idx(cfg, name)
+                narrow = bool((layer_rows[:, idx, j] <= 8).all())
+                # an interleaved stack's layer loops nest (periods, runs):
+                # there the TPU relayouts a whole image stack whose output
+                # width is no multiple of 128 unless it is stored [out, in]
+                tr = bool(cfg.layer_types) and w.shape[-1] % 128 != 0
+                return jax.lax.map(lambda a: quant(a[0], a[1], narrow, tr),
+                                   (w, layer_bits[idx, j]))
+
+            lov: dict[str, Any] = {}
+            if "w" in lp.get("qkv", {}):
+                lov["qkv"] = stacked(lp["qkv"]["w"], "qkv")
+                lov["attn_out"] = stacked(lp["attn_out"]["w"], "attn_out")
+            if "mlp" in lp and "w" in lp["mlp"]["w_in"]:
+                lov["mlp"] = {
+                    "w_in": stacked(lp["mlp"]["w_in"]["w"], "mlp_in"),
+                    "w_out": stacked(lp["mlp"]["w_out"]["w"], "mlp_out")}
+            if "ssm" in lp and "w" in lp["ssm"]["in_proj"]:
+                lov["ssm"] = {
+                    "in_proj": stacked(lp["ssm"]["in_proj"]["w"], "ssm_in"),
+                    "out_proj": stacked(lp["ssm"]["out_proj"]["w"],
+                                        "ssm_out")}
+            if "moe" in lp and "w" in lp["moe"]["router"]:
+                moev: dict[str, Any] = {
+                    "router": stacked(lp["moe"]["router"]["w"], "router")}
+                if "shared_in" in lp["moe"]:
+                    moev["shared_in"] = stacked(lp["moe"]["shared_in"]["w"],
+                                                "shared_in")
+                    moev["shared_out"] = stacked(
+                        lp["moe"]["shared_out"]["w"], "shared_out")
+                lov["moe"] = moev
+            return lov
 
         ov: dict[str, Any] = {}
         if "w" in params["embed"] and cfg.frontend != "audio":
             ov["embed"] = glob(params["embed"]["w"], 0)
         if not cfg.tie_embeddings and "w" in params.get("lm_head", {}):
             ov["lm_head"] = glob(params["lm_head"]["w"], 1)
-        lp = params["layers"]
-        lov: dict[str, Any] = {}
-        if cfg.has_attn and "w" in lp["qkv"]:
-            lov["qkv"] = stacked(lp["qkv"]["w"], "qkv")
-            lov["attn_out"] = stacked(lp["attn_out"]["w"], "attn_out")
-        if cfg.has_mlp and "w" in lp["mlp"]["w_in"]:
-            lov["mlp"] = {"w_in": stacked(lp["mlp"]["w_in"]["w"], "mlp_in"),
-                          "w_out": stacked(lp["mlp"]["w_out"]["w"], "mlp_out")}
-        if cfg.has_ssm and "w" in lp["ssm"]["in_proj"]:
-            lov["ssm"] = {
-                "in_proj": stacked(lp["ssm"]["in_proj"]["w"], "ssm_in"),
-                "out_proj": stacked(lp["ssm"]["out_proj"]["w"], "ssm_out")}
-        if cfg.family == "moe" and "w" in lp["moe"]["router"]:
-            moev: dict[str, Any] = {
-                "router": stacked(lp["moe"]["router"]["w"], "router")}
-            if "shared_in" in lp["moe"]:
-                moev["shared_in"] = stacked(lp["moe"]["shared_in"]["w"],
-                                            "shared_in")
-                moev["shared_out"] = stacked(lp["moe"]["shared_out"]["w"],
-                                             "shared_out")
-            lov["moe"] = moev
+        elif (cfg.layer_types and "w" in params["embed"]
+              and (w_rows[:, 0] != w_rows[:, 1]).any()):
+            # an interleaved stack's tied head reads the embedding's image
+            # (:func:`_image_head`); it has its own only where a profile
+            # gives the two other bits
+            ov["lm_head"] = glob(params["embed"]["w"].T, 1)
+        if cfg.layer_types:
+            lov = {kind: layer_images(params["layers"][kind],
+                                      np.asarray(layers_of(cfg, kind)))
+                   for kind in _KIND_SITES}
+            lov = {k: v for k, v in lov.items() if v}
+        else:
+            lov = layer_images(params["layers"], slice(None))
         if lov:
             ov["layers"] = lov
         return ov
@@ -1053,10 +1398,17 @@ def decode_segment(params: dict, cfg: ModelConfig, table: jax.Array,
         # per-layer KV precision row, gathered by the step's (traced)
         # profile id — like bits_row, a schedule switch never retraces
         ks = None if kv_table is None else kv_table[pid]
-        p_step = overlay_params(params, decode_image(prequant, pid))
-        logits, cch = decode_step(p_step, cfg, bits_row, tok[:, None], pos, cch,
-                                  row_valid=live, paged_backend=paged_backend,
-                                  kv_sched=ks)
+        if cfg.layer_types:
+            p_step, lay = _image_split(params, prequant, pid)
+            logits, cch = _decode_interleaved(
+                p_step, cfg, bits_row, tok[:, None], pos, cch, paged_backend,
+                ks, layer_images=lay)
+        else:
+            p_step = overlay_params(params, decode_image(prequant, pid))
+            logits, cch = decode_step(p_step, cfg, bits_row, tok[:, None],
+                                      pos, cch, row_valid=live,
+                                      paged_backend=paged_backend,
+                                      kv_sched=ks)
         # fault injection: the targeted row's logits go NaN at its fault
         # step — after the KV write (the pool stays clean), before the
         # argmax and finite-check (both token and flag see the poison)
@@ -1470,7 +1822,9 @@ def decode_segment_spec(params: dict, cfg: ModelConfig, table: jax.Array,
 
 def prefill(params: dict, cfg: ModelConfig, bits_row: jax.Array, batch: dict,
             slots: int, *, kv_bits: int = 16, return_raw_kv: bool = False,
-            kv_sched: Optional[jax.Array] = None):
+            kv_sched: Optional[jax.Array] = None,
+            ssm_into: Optional[tuple] = None,
+            images: Optional[tuple] = None):
     """Full-sequence prefill → (last-token logits [B,V], decode-ready caches).
 
     Ragged batches (``batch["prompt_len"]``): each left-padded row hands off
@@ -1486,9 +1840,26 @@ def prefill(params: dict, cfg: ModelConfig, bits_row: jax.Array, batch: dict,
     prefix registry snapshots so later shared-prefix admissions can replay
     the exact cache-fill (attention reads and int-KV scale calibration) a
     cold prefill would have done.
+
+    ``ssm_into`` ``(state, rows)``: the recurrent state is returned as
+    ``state`` (a pool's, stacked ``[L_ssm, n_slots, ...]``) with the batch's
+    final states written at pool rows ``rows`` (out of range: dropped) — in
+    an interleaved stack layer by layer, with no ``[L_ssm, B, ...]`` buffer
+    of the batch's states beside the pool.
+
+    ``images`` ``(prequant, pid)``: :func:`prequant_decode_weights`'s images
+    and the profile. An interleaved stack prefills from the profile's image
+    (the values fake-quanting its masters gives), each layer taking its own
+    slice, and never fake-quants a master; other stacks read their masters.
     """
+    layer_images = None
+    if images is not None and cfg.layer_types:
+        params, layer_images = _image_split(params, *images)
     hidden, _, collected = forward(params, cfg, bits_row, batch, collect=True,
-                                   kv_sched=kv_sched)
+                                   kv_sched=kv_sched,
+                                   state_into=(ssm_into if cfg.layer_types
+                                               else None),
+                                   layer_images=layer_images)
     b, s, _ = hidden.shape
     plen = batch.get("prompt_len")
     caches = init_caches(cfg, b, slots, kv_bits=kv_bits)
@@ -1541,6 +1912,11 @@ def prefill(params: dict, cfg: ModelConfig, bits_row: jax.Array, batch: dict,
     if cfg.has_ssm and ssm_col is not None:
         h_fin, conv_tail = ssm_col              # [L, B, H, P, N], [L, B, K-1, cd]
         caches["ssm"] = SSMState(h=h_fin, conv=conv_tail.astype(jnp.float32))
+        if ssm_into is not None and not cfg.layer_types:
+            caches["ssm"] = jax.tree.map(
+                lambda pool, row: pool.at[:, ssm_into[1]].set(row,
+                                                               mode="drop"),
+                ssm_into[0], caches["ssm"])
     logits = _logits(cfg, params, bits_row, hidden[:, -1:])[:, 0]
     if return_raw_kv:
         return logits, caches, kv_col

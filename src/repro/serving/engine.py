@@ -110,9 +110,11 @@ class ServingConfig:
     length, so later prompts can match at any block boundary).
     ``paged_backend`` — how decode reads the paged pool: ``"pallas"``
     attends in place against the blocks through the paged-attention kernel
-    (no dense view, no fold-back — the serving hot path), ``"gather"``
-    materializes the per-segment dense view (the CPU oracle path),
-    ``"auto"`` picks pallas on TPU and gather elsewhere. ``prefill_chunk``
+    (no dense view, no fold-back — the serving hot path; an interleaved
+    stack's recurrent state is then updated in place by the
+    ``ssm_decode_pallas`` kernel too), ``"gather"`` materializes the
+    per-segment dense view (the CPU oracle path), ``"auto"`` picks pallas
+    on TPU and gather elsewhere. ``prefill_chunk``
     — when set, admission prompts longer than this many tokens prefill in
     block-aligned chunks that interleave with decode segments instead of
     one monolithic wave (full-causal stacks only), smoothing the
@@ -498,8 +500,8 @@ class AdaptiveServer:
         self._collect_masters = self.masters_mode and bool(
             self.prefix_sharing or self.chunk_tokens)
 
-        def admit_paged_fn(params, profile_id, batch, slots_idx, dest, tok,
-                           pos, caches):
+        def admit_paged_fn(params, prequant, profile_id, batch, slots_idx,
+                           dest, tok, pos, caches):
             # paged admission wave: one ragged prefill into transient dense
             # rows, then one scatter of those rows into the block pool at
             # the host-chosen physical ids. ``dest[j, l]`` is the write
@@ -511,10 +513,15 @@ class AdaptiveServer:
             # ``token_idx`` left by the block's previous owner.
             bits = jnp.asarray(table)[profile_id]
             ks = None if kv_table is None else kv_table[profile_id]
+            # the recurrent state (if any) goes straight to the pool rows;
+            # an interleaved stack prefills from the weight image
             out = T.prefill(params, cfg, bits, batch, self.slots_p,
                             kv_bits=serving.kv_bits,
                             return_raw_kv=self._collect_masters,
-                            kv_sched=ks)
+                            kv_sched=ks,
+                            ssm_into=(caches["ssm"], slots_idx)
+                            if "ssm" in caches else None,
+                            images=(prequant, profile_id))
             logits, rows = out[0], out[1]
             raw = out[2] if self._collect_masters else None
             tok0 = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -522,10 +529,7 @@ class AdaptiveServer:
             caches["kv"] = self._scatter_blocks(caches["kv"], rows["kv"],
                                                 dest, slots_idx)
             if "ssm" in caches:
-                caches["ssm"] = jax.tree.map(
-                    lambda pool, row: pool.at[:, slots_idx].set(
-                        row, mode="drop"),
-                    caches["ssm"], rows["ssm"])
+                caches["ssm"] = rows["ssm"]
             plen = jnp.asarray(batch["prompt_len"], jnp.int32)
             return (tok0, raw,
                     tok.at[slots_idx].set(tok0, mode="drop"),
@@ -620,7 +624,8 @@ class AdaptiveServer:
                                     donate_argnums=(3, 4, 5))
         # paged continuous-batching primitives: same sharing story as above
         # (compiled once per server; the scheduler owns the donated pool)
-        self._admit_paged = _BoundWeights(admit_paged_fn, (params,),
+        self._admit_paged = _BoundWeights(admit_paged_fn,
+                                          (params, self._prequant),
                                           donate_argnums=(4, 5, 6))
         # shared-prefix admissions and chunked-prefill continuations share
         # the same continuation executable
@@ -680,7 +685,7 @@ class AdaptiveServer:
         Per-row scales and the block table land at pool rows ``sidx``.
         """
         nlb, bs = self.n_lblk, self.block_size
-        L = self.cfg.n_layers
+        L = rows.k.shape[0]                # layers whose KV the pool holds
         a = dest.shape[0]
 
         def blk(x):

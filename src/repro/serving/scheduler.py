@@ -112,7 +112,8 @@ pool also counts, per decode segment over its live rows, the KV blocks
 the rows hold (``kv_blocks_reserved``) against those their contexts fill
 (``kv_blocks_written``) and against their whole block tables
 (``kv_blocks_table``, which a kernel visiting every logical block reads);
-:meth:`ContinuousScheduler.paged_stats` returns all three.
+:meth:`ContinuousScheduler.paged_stats` returns all three, and the bytes
+of recurrent state the pool holds beside the blocks (``ssm_state_bytes``).
 """
 from __future__ import annotations
 
@@ -356,7 +357,9 @@ class ContinuousScheduler:
         the pool — the bench asserts it as a cross-check between the
         refcount, LRU, and free-list bookkeeping. ``kv_blocks_reserved``,
         ``kv_blocks_written`` and ``kv_blocks_table`` are the monotone KV
-        counters of :meth:`_count_kv`.
+        counters of :meth:`_count_kv`; ``ssm_state_bytes`` is the recurrent
+        state (SSM and conv windows of every row) the pool holds beside the
+        blocks.
         """
         if not self.paged:
             return {"paged": False,
@@ -377,6 +380,7 @@ class ContinuousScheduler:
             "kv_blocks_reserved": self.kv_blocks_reserved,
             "kv_blocks_written": self.kv_blocks_written,
             "kv_blocks_table": self.kv_blocks_table,
+            "ssm_state_bytes": T.cache_bytes(self._caches.get("ssm", ())),
             "kv_bytes": T.cache_bytes(self._caches),
             "registry_bytes": 0,
         }

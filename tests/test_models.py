@@ -65,6 +65,7 @@ def test_full_config_matches_brief(arch):
         "qwen2-72b": (80, 8192, 64, 8, 29568, 152064),
         "glm4-9b": (40, 4096, 32, 2, 13696, 151552),
         "granite-3-2b": (40, 2048, 32, 8, 8192, 49155),
+        "granite-4.0-h-micro": (40, 2048, 32, 8, 8192, 100352),
         "qwen1.5-110b": (80, 8192, 64, 8, 49152, 152064),
         "qwen2-vl-2b": (28, 1536, 12, 2, 8960, 151936),
         "mamba2-130m": (24, 768, 24, 24, 0, 50280),
@@ -83,6 +84,11 @@ def test_full_config_matches_brief(arch):
         assert cfg.ssm.d_state == 16 and cfg.sliding_window > 0
     if arch == "hubert-xlarge":
         assert not cfg.causal
+    if arch == "granite-4.0-h-micro":
+        assert [i for i, k in enumerate(cfg.layer_types)
+                if k == "attention"] == [5, 15, 25, 35]
+        assert (cfg.ssm.d_state, cfg.ssm.head_dim, cfg.ssm.n_groups,
+                cfg.rope) == (128, 64, 1, False)
 
 
 def test_shape_skip_rules():
