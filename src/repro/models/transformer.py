@@ -818,7 +818,8 @@ def decode_step(params: dict, cfg: ModelConfig, bits_row: jax.Array,
                 tokens: jax.Array, pos: jax.Array, caches: dict,
                 row_valid: Optional[jax.Array] = None,
                 paged_backend: str = "gather",
-                kv_sched: Optional[jax.Array] = None):
+                kv_sched: Optional[jax.Array] = None,
+                layer_images: Optional[tuple] = None):
     """One decode step. tokens ``[B,1]``, pos ``[B]`` → (logits [B,V], caches).
 
     ``row_valid`` ``[B]`` bool marks rows still generating (continuous-batching
@@ -835,21 +836,26 @@ def decode_step(params: dict, cfg: ModelConfig, bits_row: jax.Array,
     ``kv_sched`` (``int32[L]``, optional, *data*): per-layer precision-policy
     row — the step's fresh K/V are refined per layer before the cache write
     and the attention read, exactly like the prefill paths.
+
+    ``layer_images`` ``(images, img)`` (from :func:`_image_split`): the
+    layers' stacked weight images (``[n_images, L, ...]``) and the (traced)
+    image to use. Each layer grafts its own slice ``a[img, l]`` onto its
+    weights inside the layer loop, so the stack is a loop invariant and no
+    copy of a whole image is made. ``None``: the layers read ``params``.
     """
     if cfg.layer_types:
         return _decode_interleaved(params, cfg, bits_row, tokens, pos,
-                                   caches, paged_backend, kv_sched)
+                                   caches, paged_backend, kv_sched,
+                                   layer_images=layer_images)
     eb, _, layer_bits = split_bits(cfg, bits_row)
     x = embed_lookup(params["embed"], tokens, eb)
     positions = pos[:, None].astype(jnp.int32)
     b = tokens.shape[0]
 
     def body(x, xs):
-        if kv_sched is None:
-            lp, lb, cache = xs
-            ke = None
-        else:
-            lp, lb, cache, ke = xs
+        lp, lb, cache, ke, l = xs
+        if l is not None:
+            lp = _graft_layer(lp, layer_images, l)
         new_cache = dict(cache)
         if cfg.has_attn:
             xin = _norm(cfg, lp["norm_attn"], x)
@@ -893,11 +899,11 @@ def decode_step(params: dict, cfg: ModelConfig, bits_row: jax.Array,
                             gated=cfg.act == "silu", act=cfg.act)
         return x, new_cache
 
-    if kv_sched is None:
-        layers_and_caches = (params["layers"], layer_bits, caches)
-    else:
-        layers_and_caches = (params["layers"], layer_bits, caches,
-                             jnp.asarray(kv_sched, jnp.int32))
+    layers_and_caches = (
+        params["layers"], layer_bits, caches,
+        None if kv_sched is None else jnp.asarray(kv_sched, jnp.int32),
+        None if layer_images is None else jnp.arange(cfg.n_layers,
+                                                     dtype=jnp.int32))
     if cfg.scan_layers:
         x, new_caches = jax.lax.scan(body, x, layers_and_caches)
     else:  # depth-unrolled analysis variant
@@ -1043,23 +1049,30 @@ def _forward_interleaved(params: dict, cfg: ModelConfig, bits_row: jax.Array,
 
 
 def _image_split(params: dict, prequant: dict, pid: jax.Array):
-    """An interleaved stack's weights under profile ``pid``'s image: the
-    params with the global images (embedding, head) grafted on, and
-    ``(layer images, img)`` for the layer loop, where each layer takes its
-    own slice — a copy of the whole image per call would not fit beside
-    the recurrent state at full width."""
+    """The weights under profile ``pid``'s image: the params with the global
+    images (embedding, head) grafted on, and ``(layer images, img)`` for the
+    layer loop (``None`` where no layer has an image), where each layer
+    takes its own slice — a copy of the whole image per step costs a read
+    and a write of every layer's weights, and at full width would not fit
+    beside an interleaved stack's recurrent state."""
     img = prequant["image_of"][pid]
     images = dict(prequant["images"])
-    lay = images.pop("layers", {})
-    return overlay_params(params, _at(images, img)), (lay, img)
+    lay = images.pop("layers", None)
+    return (overlay_params(params, _at(images, img)),
+            (lay, img) if lay else None)
+
+
+def _graft_layer(lp: dict, layer_images: tuple, j) -> dict:
+    """Layer ``j``'s weights ``lp`` with its slice of the image
+    ``layer_images = (images [n_images, L, ...], img)`` grafted on."""
+    images, img = layer_images
+    return overlay_params(lp, jax.tree.map(lambda a: a[img, j], images))
 
 
 def _layer_weights(params: dict, kind: str, j, layer_images):
     lp = _at(params["layers"][kind], j)
     if layer_images is not None and kind in layer_images[0]:
-        img = layer_images[1]
-        lp = overlay_params(lp, jax.tree.map(lambda a: a[img, j],
-                                             layer_images[0][kind]))
+        lp = _graft_layer(lp, (layer_images[0][kind], layer_images[1]), j)
     return lp
 
 
@@ -1157,9 +1170,12 @@ def prequant_decode_weights(params: dict, cfg: ModelConfig,
     that differ only in activation bits share an image (the six paper
     profiles need two, W8 and W4). Returns ``{"image_of": int32[P],
     "images": overlay}`` where the overlay is a sparse pytree parallel to
-    ``params`` with a leading image dim; :func:`decode_image` picks a
-    profile's image inside the traced decode step and :func:`overlay_params`
-    grafts it on.
+    ``params`` with a leading image dim. :func:`decode_segment` selects a
+    profile's image inside the traced decode step without copying it out of
+    the stack: :func:`_image_split` grafts the global images (embedding,
+    head) on, and each layer grafts its own slice ``[img, l]`` inside the
+    layer loop; the layers' images are stacked ``[n_images, L, ...]`` for
+    that (per kind in an interleaved stack).
 
     A site that every image quantizes at ``<= 8`` bits is held as an int8
     carrier plus its power-of-two scale (``wq`` — a :class:`QTensor`, which
@@ -1256,8 +1272,11 @@ def prequant_decode_weights(params: dict, cfg: ModelConfig,
 
 
 def decode_image(prequant: dict, pid: jax.Array) -> dict:
-    """Profile ``pid``'s weight image (traced ``pid``) from
-    :func:`prequant_decode_weights`."""
+    """Profile ``pid``'s whole weight image (traced ``pid``) from
+    :func:`prequant_decode_weights`. Under a traced ``pid`` this copies
+    every layer's image out of the stack, so the decode segment selects per
+    layer instead (:func:`_image_split`); the speculative segment
+    (:func:`decode_segment_spec`) still takes the whole image."""
     img = prequant["image_of"][pid]
     return jax.tree.map(lambda a: a[img], prequant["images"])
 
@@ -1266,7 +1285,9 @@ def overlay_params(base: dict, overlay: dict) -> dict:
     """Graft a (sliced) prequant overlay onto the base params pytree. ``wfq``
     leaves land next to the float masters; the quantized consumers prefer
     them, and the untouched ``w`` twins are dead-code-eliminated from the
-    compiled scan."""
+    compiled scan. The decode segment grafts the global images onto the
+    whole params and each layer's image slice onto that layer's weights
+    inside the layer loop (:func:`_graft_layer`)."""
     out = dict(base)
     for k, v in overlay.items():
         if isinstance(v, dict) and isinstance(base.get(k), dict):
@@ -1398,17 +1419,13 @@ def decode_segment(params: dict, cfg: ModelConfig, table: jax.Array,
         # per-layer KV precision row, gathered by the step's (traced)
         # profile id — like bits_row, a schedule switch never retraces
         ks = None if kv_table is None else kv_table[pid]
-        if cfg.layer_types:
-            p_step, lay = _image_split(params, prequant, pid)
-            logits, cch = _decode_interleaved(
-                p_step, cfg, bits_row, tok[:, None], pos, cch, paged_backend,
-                ks, layer_images=lay)
-        else:
-            p_step = overlay_params(params, decode_image(prequant, pid))
-            logits, cch = decode_step(p_step, cfg, bits_row, tok[:, None],
-                                      pos, cch, row_valid=live,
-                                      paged_backend=paged_backend,
-                                      kv_sched=ks)
+        # the global images are grafted here; each layer grafts its own
+        # slice of the layer images inside the layer loop
+        p_step, lay = _image_split(params, prequant, pid)
+        logits, cch = decode_step(p_step, cfg, bits_row, tok[:, None], pos,
+                                  cch, row_valid=live,
+                                  paged_backend=paged_backend, kv_sched=ks,
+                                  layer_images=lay)
         # fault injection: the targeted row's logits go NaN at its fault
         # step — after the KV write (the pool stays clean), before the
         # argmax and finite-check (both token and flag see the poison)

@@ -6,26 +6,33 @@ and the batch-decode cell's 49 of 392 (49 is no multiple of the pages a
 grid step takes); and at qwen2-vl-2b-text's cell (128 rows, 2 KV heads of
 128, group 6, 6272 blocks, 49 a row). Also the Mamba-2 decode state update
 at granite-4.0-h-micro's cell (its rows, 64 heads, P 64, N 128, f32 state,
-36 layers). The TPU compiler refuses here what interpret mode never
-checks (block shapes off the (8, 128) tiling, unsupported vector relayouts),
-so these guard every change to the kernels at no chip time. They also pin
-the kernels' op names, which the benchmark's trace reduction matches.
+36 layers), and a paged dense decode segment at granite-3-2b's widths,
+whose layers read their weight images in place. The TPU compiler refuses
+here what interpret mode never checks (block shapes off the (8, 128)
+tiling, unsupported vector relayouts), so these guard every change to the
+kernels at no chip time. They also pin the kernels' op names, which the
+benchmark's trace reduction matches.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process may load the TPU library at a time, and every test worker
 imports this file.
 """
+import dataclasses
 import os
 import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import get_config
+from repro.core.profiles import paper_profiles, profile_table
 from repro.kernels.paged_attention import (paged_attention_pallas,
                                            paged_attention_pallas_multi)
 from repro.kernels.ssm_decode import ssm_decode_pallas
+from repro.models import transformer as T
 
 B, HKV, HG, D, BS, W = 8, 8, 4, 64, 16, 5
 # (N_BLOCKS, N_LBLK)
@@ -126,3 +133,83 @@ def test_ssm_decode_compiles_for_v5e_in_place(one_chip):
     assert not [ln for ln in hlo.splitlines()
                 if " copy(" in ln and ln.split("=")[1].strip().startswith(
                     state)], "the stacked state is copied"
+
+
+def _shape(dims: str) -> tuple[int, ...]:
+    """An HLO shape's dims with the unit ones dropped."""
+    return tuple(int(d) for d in dims.split(",") if d and d != "1")
+
+
+def _int8_results(hlo: str):
+    """``(opcode, dims, fused)`` of every instruction with an int8 array
+    result; ``fused`` marks an instruction inside a fusion's computation,
+    which is no buffer of its own."""
+    fused = set(re.findall(r" fusion\(.*?calls=%([\w.-]+)", hlo))
+    comp, out = None, []
+    for ln in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.-]+) .*\{$", ln)
+        if head:
+            comp = head.group(1)
+            continue
+        m = re.match(r"\s*(?:ROOT )?%\S+ = s8\[([\d,]*)\]\S* ([\w-]+)\(",
+                     ln)
+        if m:
+            out.append((m.group(2), _shape(m.group(1)), comp in fused))
+    return out
+
+
+def test_dense_segment_reads_layer_images_in_place_for_v5e(one_chip,
+                                                           monkeypatch):
+    """A paged dense decode segment (Pallas backend, the paper profiles: a
+    W8 and a W4 image) at granite-3-2b's widths: no instruction holds a
+    whole ``[L, ...]`` int8 layer image, and no copy or dynamic-slice
+    fusion makes one layer's image a buffer of its own; the dots read each
+    layer's slice out of the ``[images, L, ...]`` stack."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n_layers, rows, n_lblk, steps = 3, 8, 49, 2
+    cfg = dataclasses.replace(get_config("granite-3-2b"), n_layers=n_layers)
+    names = T.quant_layer_names(cfg)
+    table = np.asarray(profile_table(paper_profiles(names, inner_layers=[]),
+                                     names))
+
+    def masters(key):
+        return jax.tree_util.tree_map_with_path(
+            lambda p, a: a.astype(jnp.bfloat16) if p[-1].key == "w" else a,
+            T.init_params(cfg, key))
+
+    params = jax.eval_shape(masters, jax.random.PRNGKey(0))
+    prequant = jax.eval_shape(
+        lambda p: T.prequant_decode_weights(p, cfg, table), params)
+    caches = jax.eval_shape(lambda: T.init_paged_caches(
+        cfg, rows, n_lblk * BS, pool_blocks=rows * n_lblk))
+    layer_images = [a.shape for a in
+                    jax.tree.leaves(prequant["images"]["layers"])
+                    if a.dtype == jnp.int8]
+    assert {s[:2] for s in layer_images} == {(2, n_layers)}
+
+    def sds(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    def segment(params, prequant, schedule, tok, pos, caches, remaining):
+        return T.decode_segment(params, cfg, jnp.asarray(table), schedule,
+                                tok, pos, caches, remaining,
+                                prequant=prequant, paged_backend="pallas")
+
+    hlo = jax.jit(segment, donate_argnums=(5,)).lower(
+        sds(params), sds(prequant), i32(steps), i32(rows), i32(rows),
+        sds(caches), i32(rows)).compile().as_text()
+    assert _kernel_ops(hlo), "the segment lost the paged kernel"
+    results = _int8_results(hlo)
+    whole = {_shape(",".join(map(str, s[1:]))) for s in layer_images}
+    one = {tuple(sorted(_shape(",".join(map(str, s[2:]))))) for s in
+           layer_images}
+    assert not [r for r in results if r[1] in whole], \
+        "a whole layer image is selected out of the stack"
+    assert not [r for r in results
+                if not r[2] and r[0] in ("copy", "dynamic-slice", "fusion")
+                and tuple(sorted(r[1])) in one], \
+        "a layer's image slice is copied"

@@ -5,6 +5,8 @@ The carriers must be exactly the fake-quant values the decode loop would
 otherwise compute in-loop from the float masters, so serving tokens do not
 change when the images replace the in-loop quantization.
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -79,15 +81,13 @@ def test_carrier_is_the_fake_quant(parts):
             assert np.array_equal(np.asarray(got), np.asarray(want))
 
 
-@pytest.mark.parametrize("kv_bits", [16, 8])
-def test_decode_segment_images_match_in_loop_quant(parts, kv_bits):
+def _segment_matches_in_loop_quant(cfg, params, names, kv_bits, b=3):
     """``decode_segment`` through the carrier images emits the tokens (and
-    writes the KV) of the in-loop fake-quant path, with the schedule
+    writes the caches) of the in-loop fake-quant path, with the schedule
     switching across all six profiles, i.e. across both images."""
-    cfg, params, names = parts
     profs = paper_profiles(names, inner_layers=[])
     table = jnp.asarray(profile_table(profs, names))
-    b, plen, steps = 3, 7, 12
+    plen, steps = 7, 12
     prompts = np.random.default_rng(5).integers(0, cfg.vocab, (b, plen))
     logits, caches = T.prefill(params, cfg, table[0],
                                {"tokens": jnp.asarray(prompts, jnp.int32)},
@@ -102,9 +102,31 @@ def test_decode_segment_images_match_in_loop_quant(parts, kv_bits):
             params, cfg, table, schedule, tok0, pos0, c,
             jnp.full((b,), steps, jnp.int32), prequant=prequant))(caches)
 
-    ys_img, ok_img, _, _, c_img = run(T.prequant_decode_weights(
-        params, cfg, table))
+    pq = T.prequant_decode_weights(params, cfg, table)
+    assert "layers" in pq["images"]
+    ys_img, ok_img, _, _, c_img = run(pq)
     ys_ref, ok_ref, _, _, c_ref = run(in_loop)
     assert np.asarray(ok_img).all() and np.asarray(ok_ref).all()
     assert np.array_equal(np.asarray(ys_img), np.asarray(ys_ref))
-    assert np.array_equal(np.asarray(c_img["kv"].k), np.asarray(c_ref["kv"].k))
+    for a, r in zip(jax.tree.leaves(c_img), jax.tree.leaves(c_ref)):
+        assert np.array_equal(np.asarray(a), np.asarray(r))
+
+
+@pytest.mark.parametrize("kv_bits", [16, 8])
+def test_decode_segment_images_match_in_loop_quant(parts, kv_bits):
+    cfg, params, names = parts
+    _segment_matches_in_loop_quant(cfg, params, names, kv_bits)
+
+
+@pytest.mark.parametrize("scan_layers", [True, False])
+@pytest.mark.parametrize("arch", ["granite-3-2b", "qwen2-moe-a2.7b",
+                                  "mamba2-130m", "hymba-1.5b"])
+def test_decode_segment_images_match_in_loop_quant_by_family(arch,
+                                                             scan_layers):
+    """Every non-interleaved family (dense, moe, ssm, hybrid) grafts each
+    layer's image slice in its layer loop, scanned or unrolled. Four rows:
+    the MoE prefill splits its tokens into two groups."""
+    cfg = dataclasses.replace(get_smoke(arch), scan_layers=scan_layers)
+    params = T.init_params(cfg, jax.random.PRNGKey(0))
+    _segment_matches_in_loop_quant(cfg, params, T.quant_layer_names(cfg), 16,
+                                   b=4)
